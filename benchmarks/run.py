@@ -188,6 +188,9 @@ def main() -> None:
                     help="allow --json to overwrite a prior PR's "
                          "BENCH_PR*.json trajectory file")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import accuracy, speed
 

@@ -58,13 +58,17 @@ def run(report, sizes=(256, 1024, 4096, 16384, 65536)):
         report(f"speed/kissgp_n{n}", t_k * 1e6, f"N={n} t={t_k*1e3:.2f}ms")
 
 
-def _bw_util(hbm_bytes: int, seconds: float) -> float:
-    """Achieved bytes/s over the TPU-v5e HBM roofline constant. On the CPU
-    interpret backend this is the *would-be* utilization at TPU bandwidth —
-    a traffic metric for the JSON trajectory, not a measurement."""
-    from repro.launch.mesh import HBM_BW
+def _bw_util(hbm_bytes: int, seconds: float):
+    """Achieved bytes/s over the running device's HBM bandwidth from the
+    device table, or None on a device the table does not list (a CPU):
+    no bandwidth share is ever computed against another chip's peak."""
+    from repro.launch.mesh import device_spec
 
-    return hbm_bytes / max(seconds, 1e-12) / HBM_BW
+    try:
+        peak = device_spec().hbm_bw
+    except KeyError:
+        return None
+    return hbm_bytes / max(seconds, 1e-12) / peak
 
 
 def run_nd(report):
@@ -252,41 +256,23 @@ def run_serving(report, *, quick: bool = False):
 
 def run_serving_mesh(report, *, quick: bool = False):
     """Mesh-serving dimension (DESIGN.md §15; BENCH_PR8.json): warm
-    samples/s at mesh sizes 1 vs 8 virtual CPU devices, plus the
+    samples/s at mesh size 1 vs every device that exists, plus the
     fault-recovery time — a device killed mid-stream to the first
     completed slab after the detect → remesh → rewarm → replay cycle.
 
-    Runs ``repro.distributed.chaos --bench`` in a subprocess because
-    ``--xla_force_host_platform_device_count`` must be set before jax
-    initializes (the parent already holds a 1-device runtime). On CPU the
-    virtual 8-mesh is *emulation* (one physical socket timeslicing eight
-    XLA devices) — the mesh column tracks the schema and the recovery
-    path, not a parallel speedup.
+    Runs ``repro.distributed.chaos.run_bench`` in this process, on the
+    devices this process already holds: a child process could not take a
+    chip this process owns. With one device there is no mesh to measure
+    and the rows are skipped.
     """
-    import json
-    import os
-    import subprocess
-    import sys
+    import jax
 
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    src = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = (src + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else src)
-    env.pop("REPRO_BACKEND", None)  # serving path: production backend rule
-    cmd = [sys.executable, "-m", "repro.distributed.chaos", "--bench"]
-    if not quick:
-        cmd.append("--full")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(f"chaos --bench failed:\n{out.stdout}"
-                           f"\n{out.stderr}")
-    for line in out.stdout.splitlines():
-        if not line.startswith("BENCH "):
-            continue
-        row = json.loads(line[len("BENCH "):])
+    from repro.distributed.chaos import run_bench
+
+    if len(jax.devices()) < 2:
+        print("# serving_mesh: skipped (one device)")
+        return
+    for row in run_bench(quick=quick):
         if row.get("mode") == "recovery":
             report("serving_mesh/tod/recovery_s", row["recovery_s"],
                    f"device kill -> first completed slab "

@@ -26,6 +26,9 @@ from repro.launch.mesh import make_mesh
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     chart = galactic_dust_chart((8, 16, 16), n_levels=3)
     icr = ICR(chart=chart, kernel=matern32.with_defaults(rho=0.5),
               use_pallas=True)
@@ -36,11 +39,11 @@ def main():
           np.round(np.diff(np.exp(chart.axis_coords(chart.n_levels, 0)))[:5],
                    4))
 
-    # this chart's three levels fit VMEM *together*: the whole forward is
-    # ONE pyramid launch (DESIGN.md §11) — intermediate fields never touch
-    # HBM. Per level the plan reports the position-aware bytes (only the
-    # first level reads the coarse field, only the last writes the fine
-    # one) at both storage dtypes; bf16 must halve every estimate.
+    # the first two levels fit VMEM together: they run as ONE pyramid
+    # launch (DESIGN.md §11), their intermediate field never touches HBM;
+    # the third runs the single-launch megakernel. The plan reports the
+    # position-aware bytes at both storage dtypes (bf16 must halve every
+    # estimate).
     plan = dispatch.plan(chart)
     plan16 = dispatch.plan(chart, dtype="bfloat16")
     for entry, e16 in zip(plan, plan16):
@@ -50,13 +53,14 @@ def main():
               f"est HBM {hb['selected']/1e6:.2f} MB f32 / "
               f"{hb16['selected']/1e6:.2f} MB bf16 "
               f"({hb['nd-axes']/hb['selected']:.1f}x less than per-axis)")
-        assert entry["route"] == dispatch.ROUTE_PYRAMID, (
-            "dust-map level fell off the pyramid", entry)
+        want = (dispatch.ROUTE_PYRAMID if entry["level"] < 2
+                else dispatch.ROUTE_ND_FUSED)
+        assert entry["route"] == want, (
+            "dust-map level fell off its fused route", entry)
         assert hb["selected"] >= 1.9 * hb16["selected"], (hb, hb16)
 
-    # the per-level view underneath (what runs with use_pyramid=False, and
-    # what a level too big for the shared budget falls back to): the
-    # single-launch megakernel — never the jnp reference
+    # the per-level view underneath (what runs with use_pyramid=False):
+    # the single-launch megakernel — never the jnp reference
     for entry in dispatch.plan(chart, pyramid=False):
         assert entry["route"] == dispatch.ROUTE_ND_FUSED, (
             "dust-map level fell off the megakernel route", entry)

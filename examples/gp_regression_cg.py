@@ -27,6 +27,9 @@ from repro.launch.serve_gp import GPFieldServer, GPRequest, demo_posterior
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n0", type=int, default=32)
     ap.add_argument("--levels", type=int, default=3)

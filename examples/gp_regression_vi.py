@@ -36,6 +36,9 @@ from repro.launch.serve_gp import GPFieldServer, GPRequest
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--n0", type=int, default=64)

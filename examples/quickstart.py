@@ -20,6 +20,9 @@ from repro.core import (
 
 
 def main():
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     # --- 1. a GP on 200 log-spaced points (the paper's §5 setting) --------
     chart = log_chart(11, 5, n_csz=5, n_fsz=4, delta0=0.0197)
     n = chart.final_shape[0]

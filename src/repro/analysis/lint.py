@@ -190,11 +190,10 @@ def lint_vmem(chart, *, dtype=None, samples: int = 1, entries=None,
         find(loc, f"unknown route {route!r} — lint pass out of date?")
 
     if pyramid_geoms:
+        from repro.kernels.pyramid import sample_slab
+
         s_b = pyramid_s_b or 1
-        total = sum(
-            dsp._fused_tile_bytes(g, dsp._pyramid_charted(g), g.T[0], s_b,
-                                  itemsize)
-            for g in pyramid_geoms)
+        total = dsp.pyramid_bytes(pyramid_geoms, s_b=s_b, itemsize=itemsize)
         loc = f"pyramid[0..{len(pyramid_geoms) - 1}]"
         if total > budget:
             find(loc, f"pyramid residency {total} B at s_b={s_b} exceeds "
@@ -204,11 +203,11 @@ def lint_vmem(chart, *, dtype=None, samples: int = 1, entries=None,
                       "at least two resident levels")
         if s_b < samples:
             nxt = min(2 * s_b, samples)
-            grown = sum(
-                dsp._fused_tile_bytes(g, dsp._pyramid_charted(g), g.T[0],
-                                      nxt, itemsize)
-                for g in pyramid_geoms)
-            if grown <= budget:
+            if len(pyramid_geoms[0].coarse_shape) == 1:
+                nxt = sample_slab(nxt, samples, itemsize)
+            grown = dsp.pyramid_bytes(pyramid_geoms, s_b=nxt,
+                                      itemsize=itemsize)
+            if nxt > s_b and grown <= budget:
                 find(loc, f"degenerate pyramid sample block: s_b={s_b} but "
                           f"s_b={nxt} still fits the budget")
     return findings

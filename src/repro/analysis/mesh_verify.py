@@ -13,10 +13,10 @@ claim to replicate. Four passes close that gap:
 
   **collective** (:func:`check_collectives`): a per-mesh-axis
   device-variance dataflow over the body jaxpr. Each body input starts
-  varying along exactly the mesh axes its ``in_names`` shard it over;
+  varying along exactly the mesh axes its ``in_specs`` shard it over;
   ``axis_index`` introduces variance, ``psum``/``pmax``/``pmin``/
   ``all_gather`` clear it on the reduced axes, ``ppermute`` and ordinary
-  compute propagate it. An output whose ``out_names`` omit a mesh axis
+  compute propagate it. An output whose ``out_specs`` omit a mesh axis
   *claims replication* along it — if the value still carries variance
   there, the claim is unsound (under ``check_vma=False`` jax will happily
   emit one device's arbitrary answer). Axis names outside the mesh and
@@ -140,10 +140,13 @@ def _axes_param(eqn) -> tuple:
     return ()
 
 
-def _names_axes(names: dict) -> frozenset:
-    """Mesh axes a shard_map in_names/out_names entry shards over."""
+def _names_axes(spec) -> frozenset:
+    """Mesh axes a shard_map in_specs/out_specs entry (a PartitionSpec)
+    shards over."""
     out = set()
-    for axs in names.values():
+    for axs in spec:
+        if axs is None:
+            continue
         axs = (axs,) if isinstance(axs, str) else axs
         out.update(axs)
     return frozenset(out)
@@ -159,7 +162,7 @@ def _variance_walk(jaxpr, in_var, mesh_axes, entry, path, out):
     cleared), so a replication claim this walk accepts is genuinely
     reduction-backed.
     """
-    from jax.core import Literal
+    from repro.compat import Literal
 
     jaxpr = _inner_jaxpr(jaxpr)
     env = {}
@@ -231,13 +234,13 @@ def check_collectives(eqn, *, entry: str, path: str) -> list:
     findings = []
     mesh = eqn.params["mesh"]
     mesh_axes = frozenset(mesh.axis_names)
-    in_names = eqn.params["in_names"]
-    out_names = eqn.params["out_names"]
+    in_specs = eqn.params["in_specs"]
+    out_specs = eqn.params["out_specs"]
     body = _inner_jaxpr(eqn.params["jaxpr"])
-    in_var = [_names_axes(n) for n in in_names]
+    in_var = [_names_axes(n) for n in in_specs]
     out_var = _variance_walk(body, in_var, mesh_axes, entry, path,
                              findings.append)
-    for i, (names, var) in enumerate(zip(out_names, out_var)):
+    for i, (names, var) in enumerate(zip(out_specs, out_var)):
         resid = (var & mesh_axes) - _names_axes(names)
         if resid:
             findings.append(MeshFinding(
@@ -257,7 +260,7 @@ def _det_walk(jaxpr, in_taints, entry, path, out, *, replay: bool):
     body/entry input (a traced seed is derived; a baked-in PRNGKey(0) is
     not), *meshy* = influenced by ``axis_index`` or collective traffic.
     """
-    from jax.core import Literal
+    from repro.compat import Literal
 
     jaxpr = _inner_jaxpr(jaxpr)
     env = {}
@@ -667,8 +670,9 @@ def shardcheck_scenario(name: str, *, quick: bool = True, slab: int = 4,
 
             # ---- DistributedICR.apply_sqrt (abstract-eval only)
             label = f"dist_icr:{name}"
+            axes = icr.use_pallas and icr.chart.ndim > 1
             mats_s = jax.eval_shape(
-                lambda: icr.matrices(None, joint=True, axes=False))
+                lambda: icr.matrices(None, joint=not axes, axes=axes))
             sigs = {}
             for k in ring_sizes:
                 dist = DistributedICR(icr=icr,

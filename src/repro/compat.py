@@ -1,89 +1,53 @@
-"""Version compatibility shims for the pinned jax (0.4.x) vs newer APIs.
+"""The JAX API names the repo uses, resolved in one place.
 
-The repo targets the container's jax (currently 0.4.37) but tracks API names
-from newer releases. Everything version-dependent is resolved HERE, once, so
-call sites never touch ``jax.experimental`` or try/except imports themselves:
+The repo runs on the installed JAX (0.9). Names whose home moved between
+releases are imported here, once, so call sites never reach into
+``jax.core`` or ``jax.experimental`` themselves:
 
-  shard_map   — ``jax.shard_map`` (>= 0.6) or ``jax.experimental.shard_map``
-                (0.4.x, where ``check_vma`` is spelled ``check_rep``).
-  make_mesh   — ``jax.make_mesh``; passes ``axis_types`` only when the
-                installed jax has ``jax.sharding.AxisType``.
-  use_mesh    — ``jax.set_mesh`` / ``jax.sharding.use_mesh`` context manager,
-                falling back to the legacy ``with mesh:`` context on 0.4.x.
+  shard_map    — ``jax.shard_map`` (``check_vma`` keyword).
+  axis_size    — ``jax.lax.axis_size``.
+  make_mesh    — ``jax.make_mesh`` with ``Auto`` axis types by default.
+  use_mesh     — ``jax.set_mesh`` context manager.
+  Jaxpr,
+  Literal      — jaxpr node types for the static analyzers
+                 (``jax.extend.core``).
 
-tests/test_compat.py asserts the whole public API imports cleanly against the
-pinned jax.
+tests/test_compat.py asserts the whole public API imports cleanly.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Any
 
 import jax
+from jax.extend.core import Jaxpr, Literal  # noqa: F401 — re-exported
 
-# -- shard_map -----------------------------------------------------------------
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _LEGACY_SHARD_MAP
-else:
-    _LEGACY_SHARD_MAP = None
+AxisType = jax.sharding.AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               **kwargs):
-    """``jax.shard_map`` with the modern keyword signature on any jax.
-
-    On 0.4.x this resolves to ``jax.experimental.shard_map.shard_map`` and the
-    ``check_vma`` flag is translated to its old name ``check_rep``.
-    """
-    if _NEW_SHARD_MAP is not None:
-        return _NEW_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=check_vma,
-                              **kwargs)
-    return _LEGACY_SHARD_MAP(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma,
-                             **kwargs)
+    """``jax.shard_map`` with the repo's keyword signature."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (>= 0.6); on 0.4.x, ``psum(1, name)``.
-
-    Only valid inside shard_map/pmap-style contexts, like the original.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# -- meshes --------------------------------------------------------------------
-AxisType = getattr(jax.sharding, "AxisType", None)
+    """``jax.lax.axis_size`` (valid inside shard_map bodies)."""
+    return jax.lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types: Any = None,
               devices=None):
-    """``jax.make_mesh`` that only forwards ``axis_types`` when supported.
-
-    On jax 0.4.x there is no ``AxisType`` (all axes behave as the later
-    "auto" type inside shard_map), so the argument is dropped.
-    """
+    """``jax.make_mesh`` with ``Auto`` axis types unless told otherwise."""
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if AxisType is not None:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(tuple(axis_names))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, **kwargs)
 
 
 def use_mesh(mesh):
-    """Context manager making `mesh` the ambient mesh, on any jax version."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    # 0.4.x: Mesh is itself a context manager; shard_map/NamedSharding in this
-    # repo always receive the mesh explicitly, so this is belt-and-braces.
-    if hasattr(mesh, "__enter__"):
-        return mesh
-    return contextlib.nullcontext(mesh)
+    """Context manager making `mesh` the ambient mesh."""
+    return jax.set_mesh(mesh)

@@ -48,7 +48,8 @@ class ICRArchConfig:
         else:
             chart = galactic_dust_chart(self.shape0, self.n_levels,
                                         n_csz=self.n_csz, n_fsz=self.n_fsz)
-        return ICR(chart=chart, kernel=matern32.with_defaults(rho=1.0))
+        return ICR(chart=chart, kernel=matern32.with_defaults(rho=1.0),
+                   use_pallas=True)
 
 
 ICR_ARCHS = {

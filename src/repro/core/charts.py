@@ -171,15 +171,19 @@ class Chart:
         n = self.shape(level)[axis]
         return self.origin(level)[axis] + np.arange(n) * self.delta(level)[axis]
 
-    def _family_centers_idx(self, level: int, axis: int) -> np.ndarray:
-        t = np.arange(self.family_count(level, axis))
+    def _family_centers_idx(self, level: int, axis: int,
+                            families=None) -> np.ndarray:
+        t = (np.arange(self.family_count(level, axis)) if families is None
+             else np.asarray(families))
         anchor0 = self.b if self.boundary == "shrink" else 0
         return anchor0 + t * self.stride
 
-    def axis_coarse_windows(self, level: int, axis: int) -> np.ndarray:
-        """(T_a, n_csz) chart coords of each family's coarse neighbors."""
+    def axis_coarse_windows(self, level: int, axis: int,
+                            families=None) -> np.ndarray:
+        """(T_a, n_csz) chart coords of each family's coarse neighbors
+        (only of the family indices ``families``, when given)."""
         n = self.shape(level)[axis]
-        centers = self._family_centers_idx(level, axis)
+        centers = self._family_centers_idx(level, axis, families)
         idx = centers[:, None] + np.arange(-self.b, self.b + 1)[None, :]
         if self.boundary == "reflect":
             idx = np.abs(idx)
@@ -188,9 +192,11 @@ class Chart:
             assert (idx >= 0).all() and (idx < n).all()
         return self.origin(level)[axis] + idx * self.delta(level)[axis]
 
-    def axis_fine_windows(self, level: int, axis: int) -> np.ndarray:
-        """(T_a, n_fsz) chart coords of each family's fine children."""
-        centers = self._family_centers_idx(level, axis)
+    def axis_fine_windows(self, level: int, axis: int,
+                          families=None) -> np.ndarray:
+        """(T_a, n_fsz) chart coords of each family's fine children (only
+        of the family indices ``families``, when given)."""
+        centers = self._family_centers_idx(level, axis, families)
         d = self.delta(level)[axis]
         c = self.origin(level)[axis] + centers * d
         off = (np.arange(self.n_fsz) - (self.n_fsz - 1) / 2.0) * d / 2.0

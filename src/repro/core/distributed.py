@@ -32,7 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.compat import shard_map
 
 from .charts import Chart
-from .icr import ICR
+from .icr import ICR, level0_field
 from .refine import LevelGeom, refine_level
 
 Array = jnp.ndarray
@@ -113,13 +113,31 @@ class DistributedICR:
                 specs.append(P(*spec))
         return specs
 
+    def uses_axis_factors(self) -> bool:
+        """The kernel path of an N-D chart refines through the per-axis
+        Kronecker factors (the fused N-D routes), never the joint
+        matrices."""
+        return self.icr.use_pallas and self.chart.ndim > 1
+
     def mat_specs(self):
-        """PartitionSpecs for the refinement-matrix pytree."""
+        """PartitionSpecs for the refinement-matrix pytree: per-family
+        matrices along a charted shard axis are sharded with the field
+        from the first sharded level on, everything else is replicated."""
         c = self.chart
         k = self.first_sharded_level()
+        charted_shard = not c.invariant[self.shard_axis]
+        if self.uses_axis_factors():
+            rs_specs, ds_specs = [], []
+            for lvl in range(c.n_levels):
+                specs = [P()] * c.ndim
+                if lvl >= k and charted_shard:
+                    specs[self.shard_axis] = P(self.axis_names)
+                rs_specs.append(list(specs))
+                ds_specs.append(list(specs))
+            return {"sqrt0": P(), "Rax": rs_specs, "sqrtDax": ds_specs}
         r_specs, d_specs = [], []
         for lvl in range(c.n_levels):
-            if lvl >= k and not c.invariant[self.shard_axis]:
+            if lvl >= k and charted_shard:
                 spec = [None] * (c.ndim + 2)
                 spec[self.shard_axis] = self.axis_names
                 r_specs.append(P(*spec))
@@ -142,21 +160,27 @@ class DistributedICR:
         xis = [ns(s) for s in self.xi_specs()]
         return mats, xis, ns(self.out_spec())
 
-    def _refine(self, field: Array, xl: Array, r: Array, d: Array,
+    def _refine(self, field: Array, xl: Array, mats: dict, lvl: int,
                 geom: LevelGeom) -> Array:
         """Interior compute of one level — per-device, identical math to the
         single-device path. With ``icr.use_pallas`` it goes through
-        ``dispatch.refine`` (the fused 1-D kernels where the geometry is
-        covered, honoring the dtype policy; N-D levels have no axis factors
-        under the joint sharding specs and dispatch falls back to the jnp
-        reference there), else straight to ``refine_level``."""
-        if not self.icr.use_pallas:
-            return refine_level(field, xl, r, d, geom)
+        ``dispatch.refine`` (the fused kernels on every device, honoring the
+        dtype policy; N-D levels get their per-axis factors), else straight
+        to ``refine_level``."""
         from repro.kernels import dispatch
 
+        if not self.icr.use_pallas:
+            dispatch.require_reference_allowed()
+            return refine_level(field, xl, mats["R"][lvl],
+                                mats["sqrtD"][lvl], geom)
         pol = (self.icr.policy if self.icr.dtype_policy is not None
                else None)
-        return dispatch.refine(field, xl, r, d, geom, policy=pol)
+        if "Rax" in mats:
+            return dispatch.refine(
+                field, xl, None, None, geom, policy=pol,
+                axis_mats=(mats["Rax"][lvl], mats["sqrtDax"][lvl]))
+        return dispatch.refine(field, xl, mats["R"][lvl],
+                               mats["sqrtD"][lvl], geom, policy=pol)
 
     # -- the sharded program ----------------------------------------------------
     def _halo_exchange(self, local: Array, b: int) -> Array:
@@ -227,12 +251,11 @@ class DistributedICR:
         fsz = c.n_fsz**nd
 
         # replicated prologue (levels < k): identical on every device
-        field = (mats["sqrt0"] @ xi[0]).reshape(c.shape0)
+        field = level0_field(mats["sqrt0"], xi[0]).reshape(c.shape0)
         for lvl in range(k):
             geom = LevelGeom.for_level(c, lvl)
             xl = xi[lvl + 1].reshape(-1, fsz)
-            field = self._refine(field, xl, mats["R"][lvl],
-                                 mats["sqrtD"][lvl], geom)
+            field = self._refine(field, xl, mats, lvl, geom)
 
         # transition: slice my block along shard_axis
         blk = c.shape(k)[self.shard_axis] // self.n_dev
@@ -246,8 +269,7 @@ class DistributedICR:
             padded = self._pad_unsharded_axes(padded)
             geom = self._local_geom(lvl, sharded=True)
             xl = xi[lvl + 1].reshape(-1, fsz)
-            r, d = mats["R"][lvl], mats["sqrtD"][lvl]
-            field = self._refine(padded, xl, r, d, geom)
+            field = self._refine(padded, xl, mats, lvl, geom)
         return field
 
     def apply_sqrt(self, mats: dict, xi: Sequence[Array]) -> Array:
@@ -277,10 +299,10 @@ class DistributedICR:
         ]
 
     def matrices(self, theta=None):
-        # the sharded body runs the joint reference path: force the joint
-        # build (a pallas N-D ICR skips it by default) and skip the per-axis
-        # factors, which have no sharding spec
-        mats = self.icr.matrices(theta, joint=True, axes=False)
+        # the kernel path of an N-D chart needs only the per-axis factors;
+        # the reference path (use_pallas=False) the joint matrices
+        axes = self.uses_axis_factors()
+        mats = self.icr.matrices(theta, joint=not axes, axes=axes)
         mat_sh, _, _ = self.shardings()
         return jax.tree.map(jax.device_put, mats, mat_sh)
 

@@ -39,6 +39,19 @@ from .refine import (
 Array = jnp.ndarray
 
 
+def level0_field(sqrt0: Array, xi0: Array) -> Array:
+    """The dense level-0 field ``sqrt0 @ ξ_0`` (``xi0`` is one excitation
+    vector or a leading-sample batch of them), at full float32 precision:
+    the TPU's default would round the operands to bf16 and put a ~1e-3
+    relative error into every field before the first refinement level.
+    Every path that starts a field (single device, the chart-sharded ring)
+    goes through here."""
+    hi = jax.lax.Precision.HIGHEST
+    if xi0.ndim == 1:
+        return jnp.matmul(sqrt0, xi0, precision=hi)
+    return jnp.matmul(xi0, sqrt0.T, precision=hi)
+
+
 @dataclasses.dataclass(frozen=True)
 class ICR:
     """Iterative Charted Refinement model over `chart` with `kernel`.
@@ -127,23 +140,27 @@ class ICR:
                       if axes is None else axes)
         build_joint = (not build_axes) if joint is None else joint
         k = self.kernel(theta)
-        out = {"sqrt0": level0_sqrt(self.chart, k, jitter=self.jitter)}
-        if build_joint:
-            out["R"], out["sqrtD"] = [], []
-            for lvl in range(self.chart.n_levels):
-                r, sd = refinement_matrices_level(
-                    self.chart, k, lvl, jitter=self.jitter
-                )
-                out["R"].append(r)
-                out["sqrtD"].append(sd)
-        if build_axes:
-            out["Rax"], out["sqrtDax"] = [], []
-            for lvl in range(self.chart.n_levels):
-                rs, ds = axis_refinement_matrices_level(
-                    self.chart, k, lvl, jitter=self.jitter
-                )
-                out["Rax"].append(rs)
-                out["sqrtDax"].append(ds)
+        # D = K_ff - R K_cf cancels to a tiny remainder on fine levels: the
+        # TPU's default matmul (bf16 operands) left a 3.6% error in a tod
+        # field (v5e, 12 levels); float32 products everywhere
+        with jax.default_matmul_precision("highest"):
+            out = {"sqrt0": level0_sqrt(self.chart, k, jitter=self.jitter)}
+            if build_joint:
+                out["R"], out["sqrtD"] = [], []
+                for lvl in range(self.chart.n_levels):
+                    r, sd = refinement_matrices_level(
+                        self.chart, k, lvl, jitter=self.jitter
+                    )
+                    out["R"].append(r)
+                    out["sqrtD"].append(sd)
+            if build_axes:
+                out["Rax"], out["sqrtDax"] = [], []
+                for lvl in range(self.chart.n_levels):
+                    rs, ds = axis_refinement_matrices_level(
+                        self.chart, k, lvl, jitter=self.jitter
+                    )
+                    out["Rax"].append(rs)
+                    out["sqrtDax"].append(ds)
         pol = self.policy
         if jnp.dtype(pol.storage_dtype) != jnp.float32:
             # matrix *math* stays f32 (solves/eigh above); only what is
@@ -220,7 +237,10 @@ class ICR:
         chain — see the note in kernels/dispatch.py).
         """
         start = 0
+        from repro.kernels import dispatch, pyramid
+
         if not self.use_pallas:
+            dispatch.require_reference_allowed()
             for lvl in range(self.chart.n_levels):
                 geom = LevelGeom.for_level(self.chart, lvl)
                 ref = lambda f, x: refine_level(
@@ -229,8 +249,7 @@ class ICR:
                          else ref(field, xi[lvl + 1]))
             return field
 
-        from repro.kernels import dispatch, pyramid
-
+        backend = dispatch.select_backend()
         pol = self.policy if self.dtype_policy is not None else None
         if pol is not None:
             field = field.astype(pol.storage_dtype)
@@ -250,6 +269,7 @@ class ICR:
             field = pyramid.refine_pyramid(
                 field, [xi[l + 1] for l in range(start)], pmats, geoms,
                 sample_axis=sample_axis, sample_block=s_b,
+                interpret=dispatch.pyramid_interpret(backend),
                 accum_dtype=(pol.accum_name if pol is not None
                              else "float32"),
             )
@@ -263,13 +283,17 @@ class ICR:
             d = mats["sqrtD"][lvl] if "sqrtD" in mats else None
             field = dispatch.refine(
                 field, xi[lvl + 1], r, d, geom, axis_mats=axis_mats,
-                sample_axis=sample_axis, policy=pol,
+                sample_axis=sample_axis, policy=pol, backend=backend,
             )
+            # keep XLA from fusing one level's phase-plane relayouts into
+            # the next level's: at 10^8 points such fusions took minutes to
+            # compile for a v5e (the dust slab: 224 s, 18 s with barriers)
+            field = jax.lax.optimization_barrier(field)
         return field
 
     def apply_sqrt(self, mats: dict, xi: Sequence[Array]) -> Array:
         """Apply sqrt(K_ICR) to ξ (paper Alg. 1). Returns the finest field."""
-        field = (mats["sqrt0"] @ xi[0]).reshape(self.chart.shape0)
+        field = level0_field(mats["sqrt0"], xi[0]).reshape(self.chart.shape0)
         return self._refine_levels(mats, xi, field, sample_axis=False)
 
     def apply_sqrt_batch(self, mats: dict, xi: Sequence[Array]) -> Array:
@@ -286,8 +310,8 @@ class ICR:
         falls back to a vmap of the per-level jnp apply.
         """
         n_s = xi[0].shape[0]
-        field = (xi[0] @ mats["sqrt0"].T).reshape(
-            (n_s,) + self.chart.shape0)
+        field = level0_field(mats["sqrt0"], xi[0])
+        field = field.reshape((n_s,) + self.chart.shape0)
         return self._refine_levels(mats, xi, field, sample_axis=True)
 
     def sample_batch(self, key, n: int, theta=None,

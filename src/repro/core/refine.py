@@ -37,13 +37,13 @@ def _family_positions(chart: Chart, level: int):
     """
     coarse_axes, fine_axes, full_T, kept_T = [], [], [], []
     for a in range(chart.ndim):
-        cw = chart.axis_coarse_windows(level, a)
-        fw = chart.axis_fine_windows(level, a)
-        full_T.append(cw.shape[0])
-        if chart.invariant[a]:
-            # representative family: interior one (away from reflect boundary)
-            rep = min(cw.shape[0] - 1, chart.b)
-            cw, fw = cw[rep : rep + 1], fw[rep : rep + 1]
+        t_a = chart.family_count(level, a)
+        # invariant axis: one representative family, the interior one (away
+        # from a reflect boundary); the others' windows are never built
+        fams = [min(t_a - 1, chart.b)] if chart.invariant[a] else None
+        cw = chart.axis_coarse_windows(level, a, fams)
+        fw = chart.axis_fine_windows(level, a, fams)
+        full_T.append(t_a)
         coarse_axes.append(cw)
         fine_axes.append(fw)
         kept_T.append(cw.shape[0])
@@ -71,6 +71,13 @@ def _nd_points(axes_windows: Sequence[Array]) -> Array:
     """
     grids = jnp.meshgrid(*axes_windows, indexing="ij")
     return jnp.stack([g.reshape(-1) for g in grids], axis=-1)
+
+
+# Batched small solves and eigendecompositions pad every family's matrix
+# to an (8, 128) tile on the TPU, ~4 KB a family: a 1-D charted level of
+# 8.4M families needed 32 GB at once. Past this many families the batch
+# runs in chunks (~1 GB each).
+FAMILY_CHUNK = 1 << 18
 
 
 def refinement_matrices_level(chart: Chart, kernel_fn: Callable, level: int,
@@ -105,8 +112,17 @@ def refinement_matrices_level(chart: Chart, kernel_fn: Callable, level: int,
         in_axes = ([0 if i == a else None for i in range(nd)],
                    [0 if i == a else None for i in range(nd)])
         fn = jax.vmap(fn, in_axes=in_axes)
+    # one program per level (and chunk shape): run eagerly, each of its
+    # ~50 ops compiles on its own, ~0.3 s apiece on a TPU
+    fn = jax.jit(fn)
     cws = [jnp.asarray(coarse_axes[a]) for a in range(nd)]
     fws = [jnp.asarray(fine_axes[a]) for a in range(nd)]
+    n = cws[0].shape[0]
+    if nd == 1 and n > FAMILY_CHUNK:
+        parts = [fn([cws[0][i:i + FAMILY_CHUNK]], [fws[0][i:i + FAMILY_CHUNK]])
+                 for i in range(0, n, FAMILY_CHUNK)]
+        return (jnp.concatenate([p[0] for p in parts]),
+                jnp.concatenate([p[1] for p in parts]))
     r, sqrt_d = fn(cws, fws)
     return r, sqrt_d
 
@@ -142,11 +158,10 @@ def axis_refinement_matrices_level(chart: Chart, kernel_fn: Callable,
 
     rs, ds = [], []
     for a in range(nd):
-        cw = jnp.asarray(chart.axis_coarse_windows(level, a))  # (T_a, csz)
-        fw = jnp.asarray(chart.axis_fine_windows(level, a))    # (T_a, fsz)
-        if chart.invariant[a]:
-            rep = min(cw.shape[0] - 1, chart.b)
-            cw, fw = cw[rep : rep + 1], fw[rep : rep + 1]
+        fams = ([min(chart.family_count(level, a) - 1, chart.b)]
+                if chart.invariant[a] else None)
+        cw = jnp.asarray(chart.axis_coarse_windows(level, a, fams))
+        fw = jnp.asarray(chart.axis_fine_windows(level, a, fams))
 
         def one_family(cw_t, fw_t, axis=a):
             def pts(wins):
@@ -171,7 +186,7 @@ def axis_refinement_matrices_level(chart: Chart, kernel_fn: Callable,
                 k_ff = k_ff / k0
             return r, _psd_sqrt(d, jitter * jnp.mean(jnp.diag(k_ff)))
 
-        r, sqrt_d = jax.vmap(one_family)(cw, fw)
+        r, sqrt_d = jax.jit(jax.vmap(one_family))(cw, fw)
         if chart.invariant[a]:
             r, sqrt_d = r[0], sqrt_d[0]
         rs.append(r)
@@ -180,10 +195,22 @@ def axis_refinement_matrices_level(chart: Chart, kernel_fn: Callable,
 
 
 def level0_sqrt(chart: Chart, kernel_fn: Callable, *, jitter: float = 1e-6):
-    """Exact Cholesky sqrt of the level-0 kernel matrix (small by design)."""
+    """Exact PSD sqrt of the level-0 kernel matrix (small by design).
+
+    A concrete matrix is factored on the host's CPU device when the
+    default device is an accelerator, and the factor moved back: the TPU's
+    eigh of a 4096-point level 0 (the dust chart) compiled for ~500 s and
+    took ~20 GiB of host memory, where LAPACK takes seconds. A traced one
+    (θ learned inside a jitted step) stays in the trace."""
     pos = chart.grid_positions(0)
     k = kernel_matrix(kernel_fn, pos)
-    return _psd_sqrt(0.5 * (k + k.T), jitter * jnp.mean(jnp.diag(k)))
+    k, eps = 0.5 * (k + k.T), jitter * jnp.mean(jnp.diag(k))
+    if jax.default_backend() == "cpu" or isinstance(k, jax.core.Tracer):
+        return _psd_sqrt(k, eps)
+    cpu = jax.devices("cpu")[0]
+    dev = next(iter(k.devices()))
+    return jax.device_put(
+        _psd_sqrt(jax.device_put(k, cpu), jax.device_put(eps, cpu)), dev)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,7 +229,12 @@ class LevelGeom:
 
     @classmethod
     def for_level(cls, chart: Chart, level: int) -> "LevelGeom":
-        _, _, full_T, kept_T = _family_positions(chart, level)
+        # counts only: ``_family_positions`` would build every family's
+        # window coordinates (gigabytes at deployment sizes)
+        full_T = tuple(chart.family_count(level, a)
+                       for a in range(chart.ndim))
+        kept_T = tuple(1 if chart.invariant[a] else t
+                       for a, t in enumerate(full_T))
         return cls(
             coarse_shape=chart.shape(level),
             fine_shape=chart.shape(level + 1),

@@ -33,10 +33,12 @@ from __future__ import annotations
 import os
 
 if __name__ == "__main__":  # before jax initializes (conftest rule: only
-    # standalone drivers may set XLA_FLAGS, never the test process)
+    # standalone drivers may set XLA_FLAGS, never the test process). The
+    # flag only multiplies *host* devices: the harness runs on whatever
+    # platform JAX finds (the caller pins JAX_PLATFORMS=cpu for a virtual
+    # 8-device mesh)
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import dataclasses
 import json

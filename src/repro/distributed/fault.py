@@ -41,6 +41,28 @@ class DeviceLossError(RuntimeError):
             message or f"lost device(s) {list(self.device_ids)}")
 
 
+# Messages of deterministic compile/lowering failures: re-running the slab
+# recompiles the same program and fails the same way.
+_COMPILE_MARKERS = ("Mosaic failed to compile", "compile permanent error",
+                    "Unimplemented primitive", "LoweringException",
+                    "failed to legalize", "RESOURCE_EXHAUSTED")
+
+
+def retryable(exc: BaseException) -> bool:
+    """True for a transient runtime failure worth re-running in place.
+
+    Tracing and lowering errors (``TypeError``, ``ValueError``,
+    ``NotImplementedError``, Pallas lowering exceptions) and compile
+    failures (a kernel Mosaic refuses, a program that does not fit HBM)
+    are deterministic: they propagate at once instead of being retried.
+    """
+    if isinstance(exc, NotImplementedError) \
+            or not isinstance(exc, RuntimeError):
+        return False
+    msg = str(exc)
+    return not any(m in msg for m in _COMPILE_MARKERS)
+
+
 @dataclasses.dataclass
 class StragglerMonitor:
     threshold_mads: float = 6.0
@@ -141,8 +163,8 @@ class ServingFaultSupervisor:
             except DeviceLossError:
                 self.device_losses += 1
                 raise
-            except Exception:  # noqa: BLE001 — runtime/collective errors
-                if attempt >= self.retry.max_retries:
+            except Exception as exc:  # noqa: BLE001 — runtime/collective
+                if attempt >= self.retry.max_retries or not retryable(exc):
                     raise
                 self.transient_retries += 1
                 time.sleep(self.retry.backoff(attempt))

@@ -2,6 +2,8 @@
 
   launch.py     — declarative LaunchPlan records every kernel launches
                   through (DESIGN.md §14) and the verifier analyzes
+  planes.py     — phase-plane tiles and the shared in-kernel contraction
+                  step every kernel body runs (DESIGN.md §3)
   icr_refine.py — pl.pallas_call kernels (stationary + charted variants),
                   forward AND adjoint, glued by jax.custom_vjp
   nd.py         — fused N-D refinement as per-axis 1-D passes

@@ -2,18 +2,18 @@
 
 One refinement application (paper Eq. 9) can execute three ways:
 
-  * ``"pallas"``    — the fused TPU kernels (icr_refine.py, nd_fused.py);
-                      chosen on TPU.
+  * ``"pallas"``    — the fused kernels (icr_refine.py, nd_fused.py,
+                      pyramid.py) compiled by Mosaic; the only backend on
+                      a TPU, where asking for another one is an error.
   * ``"interpret"`` — the same kernels in Pallas interpret mode (the body
-                      runs as pure jnp); the CI/test harness off-TPU, so
-                      CPU/GPU runs exercise the exact BlockSpec tiling
-                      bit-for-bit (``REPRO_BACKEND=interpret``).
+                      runs as pure jnp); the CPU test harness
+                      (``REPRO_BACKEND=interpret``), which runs the exact
+                      BlockSpec tiling.
   * ``"reference"`` — the jnp oracle path: ``core.refine.refine_level``
                       for joint matrices, ``kernels.ref.refine_axes_ref``
                       for structured N-D levels carrying only the per-axis
-                      factors; chosen off-TPU in production (interpret mode
-                      is slower than plain jnp on CPU) and the fallback for
-                      anything the kernels don't cover.
+                      factors; the CPU default (interpret mode is slower
+                      than plain jnp there).
 
 Routing is decided per level from the geometry alone:
 
@@ -22,7 +22,7 @@ Routing is decided per level from the geometry alone:
   N-D, tile fits VMEM        -> single-launch fused level megakernel
                                 (repro.kernels.nd_fused, DESIGN.md §10)
   N-D, tile too large        -> per-axis fused passes (repro.kernels.nd)
-  otherwise                  -> reference
+  N-D without axis factors   -> reference (an error on the pallas backend)
 
 On top of the per-level routes, ``plan()``/``ICR.apply_sqrt`` overlay the
 **pyramid** route (repro.kernels.pyramid, DESIGN.md §11): all consecutive
@@ -35,10 +35,11 @@ the storage itemsize (bf16 halves it, doubling what fits per tile) and
 ``plan(dtype=...)`` reports HBM bytes at the policy's storage dtype —
 the byte model grows a dtype column.
 
-This replaces the ad-hoc shape guards that used to live in the retired
-``repro.kernels.ops`` shim. VMEM tile sizes (``block_families`` for the 1-D
-kernels, the ``(b_f, s_b)`` family/sample blocks for the N-D megakernel)
-are autotuned against a per-core VMEM budget instead of being hard-coded.
+VMEM tile sizes (``block_families`` for the 1-D kernels, the ``(b_f,
+s_b)`` family/sample blocks for the N-D megakernel, the pyramid's sample
+slab) are autotuned against half of the target chip's VMEM (the
+``launch/mesh.py`` device table), with models that count the padded,
+double-buffered blocks and the kernel body's float32 values.
 
 ``refine`` is fully differentiable on every route: the kernel entry points
 carry hand-written adjoint Pallas kernels via ``jax.custom_vjp``
@@ -51,12 +52,14 @@ to the forward, plus the per-level HBM-byte estimates of
 """
 from __future__ import annotations
 
+import math
 import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.refine import LevelGeom, refine_level
+from repro.launch.mesh import target_spec
 from repro.roofline.level_traffic import refine_level_traffic
 
 from . import nd as _nd
@@ -65,6 +68,7 @@ from .icr_refine import (
     refine_charted_pallas,
     refine_stationary_pallas,
 )
+from .launch import padded_block_elems
 from .policy import DtypePolicy, resolve as resolve_policy
 
 Array = jnp.ndarray
@@ -80,10 +84,25 @@ ROUTE_AXES_ND = "nd-axes"
 ROUTE_PYRAMID = "pyramid"
 ROUTE_REFERENCE = "reference"
 
-# ~half of a TPU core's VMEM (launch.mesh.VMEM_BYTES = 128 MiB): the pipeline
-# double-buffers every Blocked operand, and we leave headroom for the
-# compiler's own temporaries.
-VMEM_BUDGET_BYTES = 64 * 2**20
+# Half of the target chip's VMEM (launch/mesh.py device table): the
+# working-set models below count the double-buffered blocks AND the kernel
+# body's float32 temporaries; kernels.launch hands Mosaic a larger scoped
+# limit derived from the same entry, so an autotuned tile always compiles.
+VMEM_BUDGET_BYTES = target_spec().vmem_bytes // 2
+
+_DTYPE_OF_ITEMSIZE = {4: "float32", 2: "bfloat16"}
+
+# Largest block value, in padded elements (64 vector registers): Mosaic
+# unrolls every vector op of a kernel body over the whole block, so the
+# compile time grows with it (a 1024-register 1-D block took minutes to
+# compile for a v5e); bigger blocks buy nothing once the grid step
+# overhead is amortized.
+MAX_BLOCK_ELEMS = 1 << 16
+
+
+def _f32_bytes(shape) -> int:
+    """VMEM bytes of one float32 value of ``shape`` inside a kernel body."""
+    return 4 * padded_block_elems(shape, 4)
 
 
 def block1d_bytes(t: int, n_csz: int, n_fsz: int, *, charted: bool,
@@ -93,51 +112,63 @@ def block1d_bytes(t: int, n_csz: int, n_fsz: int, *, charted: bool,
     autotuners grow against, and the static re-derivation the VMEM lint
     pass checks autotuned plans with — repro.analysis, DESIGN.md §13).
 
-    Per grid step the kernel holds: the coarse block + its halo view
-    (``2*b_f*s``), the xi block and the output block (``2*b_f*n_fsz``) —
-    each times the ``batch_block`` slab — and the matrices: shared
-    ``(n_fsz, n_csz)+(n_fsz, n_fsz)`` when stationary, per-family (scaling
-    with ``b_f``) when charted. Everything is double buffered by the Pallas
-    pipeline, hence the factor 2.
+    The double-buffered blocks of the forward launch plan at this tile
+    (tile padding included), plus the body's float32 values: the coarse
+    planes with their halo, one window, ξ, the ``n_fsz`` accumulators and
+    their stacked copy, and the coefficient planes.
     """
-    s = max(1, n_fsz // 2)
-    b_f, b_b = block_families, max(1, batch_block)
-    per = b_b * (2 * b_f * s + 2 * b_f * n_fsz) \
-        + n_fsz * n_csz + n_fsz * n_fsz
-    if charted:
-        per += b_f * (n_fsz * n_csz + n_fsz * n_fsz)
-    return 2 * itemsize * per
+    from .icr_refine import refine_fwd_launch_plan
+
+    plan = refine_fwd_launch_plan(
+        batch=max(1, batch_block), t=t, coarse_len=0, n_csz=n_csz,
+        n_fsz=n_fsz, block_families=block_families,
+        batch_block=batch_block, dtype=_DTYPE_OF_ITEMSIZE[itemsize],
+        accum_dtype="float32", charted=charted)
+    p = plan.params
+    s, b_b, tb = p["s"], p["b_b"], p["b_f"]
+    ext = 2 * tb if p["halo"] else p["lp"]
+    rows = n_fsz * (n_csz + n_fsz)
+    temps = (_f32_bytes((s, b_b, ext))
+             + (1 + 3 * n_fsz) * _f32_bytes((b_b, tb))
+             + _f32_bytes((rows, tb if charted else 1)))
+    return plan.block_bytes() + temps
 
 
 def block1d_floor(t: int, n_csz: int, n_fsz: int) -> int:
-    """Smallest family block the 1-D kernels accept: ``min(8, t)`` but
-    never below ``q_max = (n_csz-1)//s`` — the one-block halo view must
-    cover the window overhang. The floor is returned by the autotuner
-    whether or not it fits the budget (a level cannot tile finer)."""
-    return max(min(8, t), halo_floor(n_csz, n_fsz), 1)
+    """Smallest family block the 1-D kernels accept: one lane tile (128
+    families), or the whole level when it has no more families than that.
+    The floor is returned by the autotuner whether or not it fits the
+    budget (a level cannot tile finer)."""
+    return t if t <= 128 else 128
 
 
 def autotune_block_families(t: int, n_csz: int, n_fsz: int, *, charted: bool,
                             batch_block: int = 1, itemsize: int = 4,
                             vmem_budget: int = VMEM_BUDGET_BYTES) -> int:
-    """Largest power-of-two family block whose working set (the
-    ``block1d_bytes`` model) fits the budget, clamped to the family count
-    ``t`` (a block larger than the level is pure padding — tiny levels
-    used to get the floor of 8 regardless of ``t``) and floored at
+    """Family block of the 1-D kernels: the whole level in one block when
+    its working set (the ``block1d_bytes`` model) fits the budget, else
+    the largest power-of-two multiple of 128 lanes that does, floored at
     ``block1d_floor`` (always returned, budget-fitting or not).
     """
+    from .icr_refine import sublane_tile
+
+    rows = -(-max(1, batch_block) // sublane_tile(itemsize)) \
+        * sublane_tile(itemsize)
+
+    def fits(b_f):
+        lanes = -(-(b_f + halo_floor(n_csz, n_fsz)) // 128) * 128
+        return (rows * lanes <= MAX_BLOCK_ELEMS
+                and block1d_bytes(t, n_csz, n_fsz, charted=charted,
+                                  block_families=b_f,
+                                  batch_block=batch_block,
+                                  itemsize=itemsize) <= vmem_budget)
+
     floor = block1d_floor(t, n_csz, n_fsz)
-    best, b_f = floor, floor
-    while True:
-        ws = block1d_bytes(t, n_csz, n_fsz, charted=charted,
-                           block_families=b_f, batch_block=batch_block,
-                           itemsize=itemsize)
-        if b_f > floor and ws > vmem_budget:
-            break
-        best = b_f
-        if b_f >= t:
-            break
-        b_f = min(2 * b_f, t)
+    if floor >= t or fits(t):
+        return t
+    best = floor
+    while 2 * best < t and fits(2 * best):
+        best *= 2
     return best
 
 
@@ -145,77 +176,79 @@ def autotune_batch_block(samples: int, t: int, n_csz: int, n_fsz: int, *,
                          charted: bool, block_families: int,
                          itemsize: int = 4,
                          vmem_budget: int = VMEM_BUDGET_BYTES) -> int:
-    """Largest power-of-two sample slab the 1-D kernels can hold per grid
-    step at the given family block — the native sample-batch dimension that
-    amortizes matrix loads across batched sampling / serving."""
-    best, b_b = 1, 1
-    while True:
-        ws = block1d_bytes(t, n_csz, n_fsz, charted=charted,
-                           block_families=block_families, batch_block=b_b,
-                           itemsize=itemsize)
-        if b_b > 1 and ws > vmem_budget:
+    """Largest batch slab the 1-D kernels can hold per grid step at the
+    given family block: the whole batch, or a power-of-two multiple of the
+    sublane tile (batch rows ride the sublanes) — the native sample-batch
+    dimension that amortizes coefficient loads across batched sampling,
+    serving and the orthogonal lines of an N-D axis pass."""
+    from .icr_refine import sublane_tile
+
+    sub = sublane_tile(itemsize)
+    lanes = -(-(min(block_families, t) + halo_floor(n_csz, n_fsz))
+              // 128) * 128
+    best = min(samples, sub)
+    while best < samples:
+        nxt = min(2 * best, samples)
+        if (-(-nxt // sub) * sub * lanes > MAX_BLOCK_ELEMS
+                or block1d_bytes(t, n_csz, n_fsz, charted=charted,
+                                 block_families=block_families,
+                                 batch_block=nxt,
+                                 itemsize=itemsize) > vmem_budget):
             break
-        best = b_b
-        if b_b >= samples:
-            break
-        b_b = min(2 * b_b, samples)
+        best = nxt
     return best
+
+
+def axis_pass_tiles(batch: int, t: int, n_csz: int, n_fsz: int, *,
+                    charted: bool, itemsize: int = 4) -> tuple:
+    """``(block_families, batch_block)`` of a 1-D launch over ``batch``
+    lines — the per-axis passes of an N-D level and the megakernel's
+    composed backward, whose batch is every orthogonal line of the field:
+    the family block at one sublane tile of lines, then the line slab."""
+    from .icr_refine import sublane_tile
+
+    b_f = autotune_block_families(
+        t, n_csz, n_fsz, charted=charted, itemsize=itemsize,
+        batch_block=min(batch, sublane_tile(itemsize)))
+    b_b = autotune_batch_block(batch, t, n_csz, n_fsz, charted=charted,
+                               block_families=b_f, itemsize=itemsize)
+    return b_f, b_b
 
 
 def _fused_tile_bytes(geom: LevelGeom, charted: tuple, b_f: int, s_b: int,
                       itemsize: int) -> int:
     """VMEM working set of one megakernel grid step (model, DESIGN.md §10).
 
-    Counted: the coarse tile + its axis-0 halo view, the ξ and output tiles
-    (all double-buffered by the pipeline), the matrices (axis-0 factors
-    blocked when charted), and the peak in-flight stage of the back-to-back
-    contraction chain (input + window tensor + output of the widest stage).
+    The double-buffered blocks of the launch plan at this tile (tile
+    padding included) plus the body's float32 values: per contraction
+    stage its input, one window, the accumulators and their stacked copy
+    (at most three outputs' worth), at the widest stage.
     """
-    nd = len(geom.coarse_shape)
-    fsz, csz = geom.n_fsz, geom.n_csz
-    s = max(1, fsz // 2)
-    q = (csz - 1) // s
-    T = geom.T
-    lp_trail = []
-    for a in range(1, nd):
-        n = geom.coarse_shape[a] + (2 * geom.b if geom.boundary == "reflect"
-                                    else 0)
-        lp_trail.append(max(n, (T[a] + q) * s))
-    prod_f = 1
-    for a in range(1, nd):
-        prod_f *= T[a] * fsz
+    from .nd_fused import fused_launch_shapes, nd_fused_launch_plan
 
-    def prod(xs):
-        out = 1
-        for x in xs:
-            out *= x
-        return out
-
-    tile_in = 2 * s_b * b_f * s * prod(lp_trail)         # main + halo views
-    xi_tile = s_b * b_f * fsz * prod_f
-    out_tile = s_b * b_f * fsz * prod_f
-
-    # contraction chain peak: stage extents start at the coarse tile and
-    # graduate one axis at a time to fine resolution
-    stage = [(b_f + q) * s] + [(T[a] + q) * s for a in range(1, nd)]
+    sh = fused_launch_shapes(geom, samples=s_b, b_f=b_f, s_b=s_b)
+    plan = nd_fused_launch_plan(
+        nd=sh["nd"], csz=geom.n_csz, fsz=geom.n_fsz, T=sh["T"],
+        charted=charted, b_f=b_f, s_b=s_b, sp=sh["sp"], l0p=sh["l0p"],
+        lp_trail=sh["lp_trail"], nblk=sh["nblk"], prod_f=sh["prod_f"],
+        dtype=_DTYPE_OF_ITEMSIZE[itemsize], accum_dtype="float32")
+    nd, fsz, csz = sh["nd"], geom.n_fsz, geom.n_csz
+    layout = plan.params["layout"]
+    field = plan.operand("field")
+    phases = [field.block_shape[a] for a in range(nd)]
+    pos = {a: field.block_shape[nd + 1 + layout.index(a)] for a in range(nd)}
+    if plan.params["halo"]:
+        pos[0] *= 2
     peak = 0
     for a in range(nd - 1, -1, -1):
-        before = prod(stage)
-        win = stage.copy()
-        win[a] = (T[a] if a else b_f) * csz
-        after = stage.copy()
-        after[a] = (T[a] if a else b_f) * fsz
-        peak = max(peak, before + prod(win) + prod(after))
-        stage = after
-    scratch = s_b * peak
-
-    mats = 0
-    per = fsz * csz + fsz * fsz
-    mats += (b_f if charted[0] else 1) * per
-    for a in range(1, nd):
-        mats += (T[a] if charted[a] else 1) * per
-
-    return itemsize * (2 * (tile_in + xi_tile + out_tile + mats) + scratch)
+        before = _f32_bytes(tuple(phases) + (s_b,)
+                            + tuple(pos[x] for x in layout))
+        phases[a] = fsz
+        pos[a] = b_f if a == 0 else sh["T"][a]
+        after = _f32_bytes(tuple(phases) + (s_b,)
+                           + tuple(pos[x] for x in layout))
+        peak = max(peak, before + 3 * after)
+    return plan.block_bytes() + peak
 
 
 def autotune_nd_fused(geom: LevelGeom, *, charted: tuple | None = None,
@@ -225,29 +258,39 @@ def autotune_nd_fused(geom: LevelGeom, *, charted: tuple | None = None,
     or None when even the minimal tile busts the VMEM budget — the fallback
     rule: dispatch then routes the level to the per-axis passes.
 
-    Grows the axis-0 family block first (powers of two up to ``T_0``), then
-    the sample slab (up to ``samples``), keeping the §10 working-set model
-    under the budget.
+    Grows the axis-0 family block first (from the halo floor, doubling up
+    to ``T_0``, kept legal for the tile layout), then the sample slab (up to
+    ``samples``), keeping the §10 working-set model under the budget.
     """
+    from .nd_fused import fused_block
+
     nd = len(geom.coarse_shape)
     if nd < 2:
         return None
     if charted is None:
         charted = tuple(k > 1 for k in geom.kept_T)
+    t0 = geom.T[0]
     q = halo_floor(geom.n_csz, geom.n_fsz)
-    floor = max(min(8, geom.T[0]), q, 1)
-    if _fused_tile_bytes(geom, charted, floor, 1, itemsize) > vmem_budget:
+
+    def legal(b):
+        return fused_block(nd, t0, b, itemsize)
+
+    def fits(b, sb):
+        return _fused_tile_bytes(geom, charted, b, sb,
+                                 itemsize) <= vmem_budget
+
+    b_f = legal(max(min(q, t0), 1))
+    if not fits(b_f, 1):
         return None
-    b_f = floor
-    while b_f < geom.T[0]:
-        nxt = min(2 * b_f, geom.T[0])
-        if _fused_tile_bytes(geom, charted, nxt, 1, itemsize) > vmem_budget:
+    while b_f < t0:
+        nxt = legal(min(2 * b_f, t0))
+        if nxt <= b_f or not fits(nxt, 1):
             break
         b_f = nxt
     s_b = 1
     while s_b < samples:
         nxt = min(2 * s_b, samples)
-        if _fused_tile_bytes(geom, charted, b_f, nxt, itemsize) > vmem_budget:
+        if not fits(b_f, nxt):
             break
         s_b = nxt
     return b_f, s_b
@@ -257,37 +300,67 @@ def _pyramid_charted(geom: LevelGeom) -> tuple:
     return tuple(k > 1 for k in geom.kept_T)
 
 
+def pyramid_bytes(geoms, *, s_b: int, itemsize: int) -> int:
+    """VMEM working set of one pyramid grid step over ``geoms``: the
+    double-buffered blocks of its launch plan (polyphase tiles, padding
+    included) plus the body's float32 values at the widest contraction
+    stage of the chain (input, one window, the accumulators and their
+    stacked copy)."""
+    from .pyramid import pyramid_geometry, pyramid_launch_plan
+
+    g0 = geoms[0]
+    fsz, csz = g0.n_fsz, g0.n_csz
+    s = fsz // 2
+    levels = [(tuple(g.T), tuple(g.coarse_shape)) for g in geoms]
+    plan = pyramid_launch_plan(
+        levels=levels, charted=[_pyramid_charted(g) for g in geoms],
+        sp=s_b, s_b=s_b, csz=csz, fsz=fsz,
+        dtype=_DTYPE_OF_ITEMSIZE[itemsize], accum_dtype="float32")
+    geo = pyramid_geometry(levels, s=s, fsz=fsz)
+    nd, pos = geo["nd"], geo["pos"]
+    phases = [s] * nd
+    peak = 0
+    for _ in geoms:
+        for a in range(nd - 1, -1, -1):
+            before = _f32_bytes(tuple(phases) + (s_b,) + pos)
+            phases[a] *= 2
+            after = _f32_bytes(tuple(phases) + (s_b,) + pos)
+            peak = max(peak, before + 3 * after)
+    return plan.block_bytes() + peak
+
+
 def autotune_pyramid(geoms, *, samples: int = 1, itemsize: int = 4,
                      vmem_budget: int = VMEM_BUDGET_BYTES):
     """How many consecutive levels (from ``geoms[0]``) fit VMEM together,
     and the sample slab: ``(k, s_b)``, or None when fewer than two fit —
     a one-level "pyramid" is just the per-level route.
 
-    The residency criterion reuses the §10 working-set model at FULL
-    axis-0 extent (``b_f = T_0``, i.e. no spatial tiling): a covered
-    level's coarse+fine fields, ξ, matrices and contraction scratch are
-    all simultaneously resident, so the models simply add up. The storage
-    ``itemsize`` makes the criterion dtype-aware — bf16 fits twice the
-    levels' bytes of fp32.
+    The residency criterion is the ``pyramid_bytes`` model: every covered
+    level's ξ and coefficient planes plus the polyphase field of the whole
+    chain are simultaneously resident. The storage ``itemsize`` makes the
+    criterion dtype-aware. On a 1-D chart the sample slab rides the
+    sublanes, so it is all samples or a multiple of the sublane tile.
     """
+    from .pyramid import sample_slab
 
-    def level_bytes(geom, s_b):
-        return _fused_tile_bytes(geom, _pyramid_charted(geom), geom.T[0],
-                                 s_b, itemsize)
+    nd = len(geoms[0].coarse_shape)
 
-    k, total = 0, 0
-    for geom in geoms:
-        lb = level_bytes(geom, 1)
-        if total + lb > vmem_budget:
-            break
-        total += lb
+    def legal(sb):
+        return sample_slab(sb, samples, itemsize) if nd == 1 else sb
+
+    def fits(k, sb):
+        return pyramid_bytes(geoms[:k], s_b=sb,
+                             itemsize=itemsize) <= vmem_budget
+
+    s_b = legal(1)
+    k = 1
+    while k < len(geoms) and fits(k + 1, s_b):
         k += 1
-    if k < 2:
+    if k < 2 or not fits(k, s_b):
         return None
-    s_b = 1
     while s_b < samples:
-        nxt = min(2 * s_b, samples)
-        if sum(level_bytes(g, nxt) for g in geoms[:k]) > vmem_budget:
+        nxt = legal(min(2 * s_b, samples))
+        if nxt <= s_b or not fits(k, nxt):
             break
         s_b = nxt
     return k, s_b
@@ -317,31 +390,64 @@ def pyramid_cover(chart, *, have_axis_mats: bool | None = None,
 def select_backend(*, platform: str | None = None) -> str:
     """Kernel backend for `platform` (default: the runtime jax backend).
 
-    TPU runs the compiled Pallas kernels; everywhere else the *production*
-    answer is the jnp reference/oracle path — Pallas interpret mode emulates
-    the BlockSpec machinery step by step and is strictly slower than plain
-    jnp on CPU, so it is a correctness harness, not a serving path. When
-    ``platform`` is None (the runtime decision) the ``REPRO_BACKEND`` env
-    var overrides it (``pallas`` / ``interpret`` / ``reference``): CI sets
-    ``REPRO_BACKEND=interpret`` on its test steps so the exact kernel
-    tiling keeps running bit-for-bit off-TPU, and the tiling-coverage test
-    modules pin the same override via a monkeypatch fixture. An explicit
-    ``platform`` is a pure what-would-run-there question (``plan()``
-    introspection) and ignores the override.
+    A TPU runs the compiled Pallas kernels and nothing else: asking for
+    another backend there (``REPRO_BACKEND``) is an error, never a silent
+    fallback. The CPU answer is the jnp reference/oracle path — Pallas
+    interpret mode emulates the BlockSpec machinery step by step and is
+    strictly slower than plain jnp on CPU, so it is a correctness harness,
+    not a serving path. ``REPRO_BACKEND`` (``pallas`` / ``interpret`` /
+    ``reference``) is a test-only override of the runtime answer: the
+    tiling-coverage test modules pin ``interpret`` through a monkeypatch
+    fixture so the exact kernel tiling keeps running off-TPU. Any other
+    platform has no kernel backend at all. An explicit ``platform`` is a
+    pure what-would-run-there question (``plan()`` introspection) and
+    ignores the override.
     """
+    explicit = platform is not None
     if platform is None:
-        override = os.environ.get("REPRO_BACKEND")
-        if override:
-            if override not in (BACKEND_PALLAS, BACKEND_INTERPRET,
-                                BACKEND_REFERENCE):
-                raise ValueError(
-                    f"REPRO_BACKEND={override!r}: expected one of "
-                    f"{BACKEND_PALLAS!r}, {BACKEND_INTERPRET!r}, "
-                    f"{BACKEND_REFERENCE!r}"
-                )
-            return override
         platform = jax.default_backend()
-    return BACKEND_PALLAS if platform == "tpu" else BACKEND_REFERENCE
+    if platform == "tpu":
+        override = None if explicit else os.environ.get("REPRO_BACKEND")
+        if override and override != BACKEND_PALLAS:
+            raise RuntimeError(
+                f"REPRO_BACKEND={override!r} on a TPU: the chip runs the "
+                f"compiled {BACKEND_PALLAS!r} kernels only")
+        return BACKEND_PALLAS
+    if platform != "cpu":
+        raise RuntimeError(
+            f"no kernel backend for platform {platform!r}: the refinement "
+            f"kernels target the TPU, the jnp reference runs on the CPU")
+    override = None if explicit else os.environ.get("REPRO_BACKEND")
+    if override:
+        if override not in (BACKEND_PALLAS, BACKEND_INTERPRET,
+                            BACKEND_REFERENCE):
+            raise ValueError(
+                f"REPRO_BACKEND={override!r}: expected one of "
+                f"{BACKEND_PALLAS!r}, {BACKEND_INTERPRET!r}, "
+                f"{BACKEND_REFERENCE!r}"
+            )
+        return override
+    return BACKEND_REFERENCE
+
+
+def require_reference_allowed() -> str:
+    """The jnp reference as an explicit choice (``ICR(use_pallas=False)``,
+    the same in ``DistributedICR``): it runs on the CPU only. Anywhere
+    else the refinement kernels are the only path, and asking for the
+    reference there is an error, never a silent fallback."""
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"use_pallas=False runs the jnp reference; on {platform!r} the "
+            "refinement kernels are the only path (use_pallas=True)")
+    return BACKEND_REFERENCE
+
+
+def pyramid_interpret(backend: str):
+    """The pyramid wrapper's ``interpret`` argument for a backend."""
+    if backend == BACKEND_REFERENCE:
+        return "reference"
+    return backend != BACKEND_PALLAS
 
 
 def route_for(geom: LevelGeom, *, have_axis_mats: bool = False,
@@ -390,9 +496,9 @@ def plan(chart, *, have_axis_mats: bool | None = None,
     Each entry carries a ``"vjp"`` sub-dict describing how the *backward*
     pass of that level executes (structured routes run the hand-written
     adjoint kernels; the megakernel's backward composes the 1-D adjoints in
-    reverse axis order; the pyramid's backward replays the jnp reference
-    chain — its covered levels are VMEM-sized by construction; the
-    reference route is jnp autodiff) and an ``"hbm_bytes"`` sub-dict: the
+    reverse axis order; the pyramid's backward runs the same 1-D adjoints
+    level by level, last level first; the reference route is jnp
+    autodiff) and an ``"hbm_bytes"`` sub-dict: the
     ``roofline.level_traffic`` estimate for the selected route next to
     every candidate route, so the traffic win of the fused paths is visible
     without running anything.
@@ -460,7 +566,7 @@ def plan(chart, *, have_axis_mats: bool | None = None,
         hbm["selected"] = hbm[route]
         vjp = {
             "route": (ROUTE_REFERENCE if route == ROUTE_REFERENCE
-                      else route + ("-ref" if covered else "-adjoint")),
+                      else route + "-adjoint"),
             "backend": backend,
             "block_families": dict(blocks),
         }
@@ -548,6 +654,18 @@ def plan_signature(chart, **plan_kwargs) -> list:
     return out
 
 
+def plan_kernel(plan):
+    """The kernel body of any exported launch plan (what ``run_plan``
+    launches it with) — for compiling a plan without its operands."""
+    from . import icr_refine, nd_fused, pyramid
+
+    if plan.kernel == "refine_nd_fused":
+        return nd_fused.plan_kernel(plan)
+    if plan.kernel == "refine_pyramid":
+        return pyramid.plan_kernel(plan)
+    return icr_refine.plan_kernel(plan)
+
+
 # -- declarative launch-plan export (DESIGN.md §14) -----------------------------
 # The kernel impls build LaunchPlan records (kernels.launch) and hand them
 # to run_plan; these exports rebuild the *identical* records from geometry
@@ -605,7 +723,11 @@ def level_launch_plans(geom: LevelGeom, route: str | None = None, *,
                 refine_adjoint_launch_plan(**kw)]
 
     if route == ROUTE_ND_FUSED:
-        from .nd_fused import fused_launch_shapes, nd_fused_launch_plan
+        from .nd_fused import (
+            fused_block,
+            fused_launch_shapes,
+            nd_fused_launch_plan,
+        )
 
         charted = _pyramid_charted(geom)
         T = tuple(geom.T)
@@ -618,7 +740,9 @@ def level_launch_plans(geom: LevelGeom, route: str | None = None, *,
                 "passes")
         b_f, s_b = tuned
         if block_families is not None:
-            b_f = max(min(block_families, T[0]), q_max, 1)
+            b_f = fused_block(len(T), T[0],
+                              max(min(block_families, T[0]), q_max, 1),
+                              itemsize)
         if sample_block is not None:
             s_b = max(1, min(sample_block, samples))
         sh = fused_launch_shapes(geom, samples=samples, b_f=b_f, s_b=s_b)
@@ -628,29 +752,12 @@ def level_launch_plans(geom: LevelGeom, route: str | None = None, *,
             nd=nd, csz=csz, fsz=fsz, T=T, charted=charted, b_f=b_f,
             s_b=s_b, sp=sp, l0p=l0p, lp_trail=lp_trail, nblk=sh["nblk"],
             prod_f=prod_f, dtype=dtype, accum_dtype=accum_dtype)]
-        # fixed-matrix backward (nd_fused._core_bwd): 1-D adjoints in
+        # fixed-matrix backward (nd_fused.axes_adjoint): 1-D adjoints in
         # reverse axis order on the padded operand extents
-        t0p = sh["nblk"] * b_f
-        f_trail = tuple(T[a] * fsz for a in range(1, nd))
-        bf0 = autotune_block_families(t0p, csz, fsz, charted=charted[0])
-        plans.append(refine_adjoint_launch_plan(
-            batch=sp * prod_f, t=t0p, coarse_len=l0p, n_csz=csz, n_fsz=fsz,
-            block_families=bf0, batch_block=1, dtype=dtype,
-            accum_dtype=accum_dtype, charted=charted[0]))
-        for a in range(1, nd):
-            batch_a = sp * l0p
-            for j in range(1, a):
-                batch_a *= lp_trail[j - 1]
-            for j in range(a + 1, nd):
-                batch_a *= f_trail[j - 1]
-            bf_a = autotune_block_families(T[a], csz, fsz,
-                                           charted=charted[a])
-            plans.append(refine_adjoint_launch_plan(
-                batch=batch_a, t=T[a], coarse_len=(T[a] + q_max) * s,
-                n_csz=csz, n_fsz=fsz, block_families=bf_a, batch_block=1,
-                dtype=dtype, accum_dtype=accum_dtype, charted=charted[a],
-                noise=False))
-        return plans
+        return plans + axes_adjoint_plans(
+            T, t0p=sh["nblk"] * b_f, samples=sp,
+            coarse_lens=(l0p,) + tuple(lp_trail), charted=charted, csz=csz,
+            fsz=fsz, dtype=dtype, accum_dtype=accum_dtype)
 
     if route == ROUTE_AXES_ND:
         nd = len(geom.coarse_shape)
@@ -664,11 +771,12 @@ def level_launch_plans(geom: LevelGeom, route: str | None = None, *,
                 batch_a *= geom.coarse_shape[j]
             for j in range(a + 1, nd):
                 batch_a *= T[j] * fsz
-            bf = block_families or autotune_block_families(
-                ag.T[0], csz, fsz, charted=charted_a, itemsize=itemsize)
+            bf, bb = axis_pass_tiles(batch_a, T[a], csz, fsz,
+                                     charted=charted_a, itemsize=itemsize)
+            bf = block_families or bf
             kw = dict(batch=batch_a, t=T[a],
                       coarse_len=geom.coarse_shape[a] + pad, n_csz=csz,
-                      n_fsz=fsz, block_families=bf, batch_block=1,
+                      n_fsz=fsz, block_families=bf, batch_block=bb,
                       dtype=dtype, accum_dtype=accum_dtype,
                       charted=charted_a, noise=a == 0)
             fwd.append(refine_fwd_launch_plan(**kw))
@@ -676,6 +784,44 @@ def level_launch_plans(geom: LevelGeom, route: str | None = None, *,
         return fwd + bwd[::-1]
 
     raise ValueError(f"no launch plans for route {route!r}")
+
+
+def axes_adjoint_plans(T: tuple, *, t0p: int, samples: int, coarse_lens,
+                       charted, csz: int, fsz: int, dtype,
+                       accum_dtype: str = "float32") -> list:
+    """The launches of ``nd_fused.axes_adjoint`` (one N-D level's
+    transpose at fixed matrices): axis 0 with noise over the ``samples ·
+    prod_f`` fine columns, then each trailing axis ``noise=False`` with
+    the other axes folded into the batch — the megakernel's backward and,
+    level by level, the pyramid's."""
+    from .icr_refine import refine_adjoint_launch_plan
+
+    itemsize = jnp.dtype(dtype).itemsize
+    nd = len(T)
+    s = max(1, fsz // 2)
+    q_max = (csz - 1) // s
+    l0p, lp_trail = coarse_lens[0], tuple(coarse_lens[1:])
+    f_trail = tuple(T[a] * fsz for a in range(1, nd))
+    prod_f = math.prod(f_trail)
+    kw = dict(n_csz=csz, n_fsz=fsz, dtype=dtype, accum_dtype=accum_dtype)
+    bf0, bb0 = axis_pass_tiles(samples * prod_f, t0p, csz, fsz,
+                               charted=charted[0], itemsize=itemsize)
+    plans = [refine_adjoint_launch_plan(
+        batch=samples * prod_f, t=t0p, coarse_len=l0p, block_families=bf0,
+        batch_block=bb0, charted=charted[0], **kw)]
+    for a in range(1, nd):
+        batch_a = samples * l0p
+        for j in range(1, a):
+            batch_a *= lp_trail[j - 1]
+        for j in range(a + 1, nd):
+            batch_a *= f_trail[j - 1]
+        bf_a, bb_a = axis_pass_tiles(batch_a, T[a], csz, fsz,
+                                     charted=charted[a], itemsize=itemsize)
+        plans.append(refine_adjoint_launch_plan(
+            batch=batch_a, t=T[a], coarse_len=(T[a] + q_max) * s,
+            block_families=bf_a, batch_block=bb_a, charted=charted[a],
+            noise=False, **kw))
+    return plans
 
 
 def chart_launch_plans(chart, *, samples: int = 1, dtype=None,
@@ -689,13 +835,13 @@ def chart_launch_plans(chart, *, samples: int = 1, dtype=None,
     One group dict per launch unit: ``{"level", "route", "geom",
     "plans"}``. When the §11 pyramid cover fires, the covered prefix is
     ONE group (``route="pyramid"``, ``level=(0, k-1)``, ``geom`` the list
-    of covered geometries) whose single plan is the multi-level launch;
-    the remaining levels follow with their per-level forward + adjoint
+    of covered geometries) whose plans are the multi-level launch and
+    the adjoint launches of its backward; the remaining levels follow with their per-level forward + adjoint
     plans. Reference-routed levels appear with an empty plan list so the
     verifier can still see them. ``sample_block`` overrides the pyramid
     cover's autotuned sample block (the tile-sweep tests drive it).
     """
-    from .pyramid import pyramid_launch_plan
+    from .pyramid import pyramid_launch_plan, sample_slab
 
     if have_axis_mats is None:
         have_axis_mats = chart.ndim > 1
@@ -710,27 +856,28 @@ def chart_launch_plans(chart, *, samples: int = 1, dtype=None,
         geoms = [LevelGeom.for_level(chart, lvl) for lvl in range(k_cov)]
         fsz, csz = geoms[0].n_fsz, geoms[0].n_csz
         s_b = max(1, min(sample_block or s_b_cov or 1, samples))
+        if chart.ndim == 1:
+            s_b = sample_slab(s_b, samples, itemsize)
         sp = -(-samples // s_b) * s_b
-        xi_shapes, r_shapes, d_shapes, levels = [], [], [], []
-        for g in geoms:
+        plans = [pyramid_launch_plan(
+            levels=[(tuple(g.T), tuple(g.coarse_shape)) for g in geoms],
+            charted=[_pyramid_charted(g) for g in geoms], sp=sp,
+            s_b=s_b, csz=csz, fsz=fsz, dtype=dtype,
+            accum_dtype=accum_dtype, boundary=geoms[0].boundary,
+            b=geoms[0].b)]
+        # the backward (pyramid._pyramid_adjoint): each covered level's
+        # 1-D adjoints, last level first
+        s = max(1, fsz // 2)
+        q_max = (csz - 1) // s
+        for g in geoms[::-1]:
             T = tuple(g.T)
-            ch = _pyramid_charted(g)
-            prod_f = 1
-            for a in range(1, len(T)):
-                prod_f *= T[a] * fsz
-            xi_shapes.append((sp, T[0] * fsz, prod_f))
-            r_shapes.append([(T[a], fsz, csz) if ch[a] else (fsz, csz)
-                             for a in range(len(T))])
-            d_shapes.append((T[0], fsz, fsz) if ch[0] else (fsz, fsz))
-            levels.append((T, tuple(g.coarse_shape)))
-        groups.append({
-            "level": (0, k_cov - 1), "route": ROUTE_PYRAMID, "geom": geoms,
-            "plans": [pyramid_launch_plan(
-                field_shape=(sp,) + tuple(geoms[0].coarse_shape),
-                xi_shapes=xi_shapes, r_shapes=r_shapes, d_shapes=d_shapes,
-                levels=levels, s_b=s_b, fsz=fsz, dtype=dtype,
-                accum_dtype=accum_dtype)],
-        })
+            plans += axes_adjoint_plans(
+                T, t0p=T[0], samples=sp,
+                coarse_lens=tuple((t + q_max) * s for t in T),
+                charted=_pyramid_charted(g), csz=csz, fsz=fsz, dtype=dtype,
+                accum_dtype=accum_dtype)
+        groups.append({"level": (0, k_cov - 1), "route": ROUTE_PYRAMID,
+                       "geom": geoms, "plans": plans})
     for lvl in range(k_cov, chart.n_levels):
         geom = LevelGeom.for_level(chart, lvl)
         route = route_for(geom, have_axis_mats=have_axis_mats,
@@ -779,8 +926,13 @@ def refine(field: Array, xi: Array, r: Array, d: Array, geom: LevelGeom, *,
     itemsize = jnp.dtype(field.dtype).itemsize
     route = route_for(geom, have_axis_mats=axis_mats is not None,
                       itemsize=itemsize)
-    if backend is None and route != ROUTE_REFERENCE:
+    if backend is None:
         backend = select_backend()
+    if route == ROUTE_REFERENCE and backend == BACKEND_PALLAS:
+        raise ValueError(
+            f"level {geom.coarse_shape} has no kernel route (an N-D level "
+            "needs its per-axis factors — ICR.matrices(axes=True)); on the "
+            "chip the jnp reference is not a fallback")
     if route == ROUTE_REFERENCE or backend == BACKEND_REFERENCE:
         if r is None or d is None:
             if axis_mats is None:
@@ -826,7 +978,9 @@ def refine(field: Array, xi: Array, r: Array, d: Array, geom: LevelGeom, *,
         else:
             out = refine_level(field, xi, r, d, geom)
         return out.astype(out_dtype)
-    interpret = backend != BACKEND_PALLAS
+    if backend not in (BACKEND_PALLAS, BACKEND_INTERPRET):
+        raise ValueError(f"unknown kernel backend {backend!r}")
+    interpret = backend == BACKEND_INTERPRET
 
     if route == ROUTE_ND_FUSED:
         from . import nd_fused  # lazy: keeps import order flexible

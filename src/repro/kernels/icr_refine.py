@@ -4,61 +4,47 @@ Why a kernel: one refinement level reads the coarse field once, builds
 overlapping ``n_csz``-windows, contracts them with the stencil(s) and adds the
 correlated correction ``sqrt(D) ξ``. Done naively in XLA this materializes the
 (T, n_csz) window tensor in HBM (n_csz-fold read amplification) and runs the
-noise add as a separate elementwise pass. The fused kernel keeps the window
-construction in VMEM/VREGs and feeds the MXU directly:
+noise add as a separate elementwise pass. The fused kernel builds the
+windows in VMEM and adds the noise in the same pass.
 
-  HBM traffic per level  : read L + read T·n_fsz (ξ) + write T·n_fsz
-  naive XLA              : + read/write T·n_csz (window tensor materialized)
+Layout (DESIGN.md §3, ``planes.py``): families ride the 128 lanes. Outside
+the kernel the halo-padded coarse field ``(B, L)`` is split into ``s =
+n_fsz//2`` phase planes ``(s, B, L/s)``, so window column ``k`` of family
+``t`` is ``plane[k % s][t + k // s]`` — a lane-offset slice, no gather. ξ
+and the output are ``(n_fsz, B, T)`` planes (child ``f`` of every family in
+plane ``f``), and the refinement matrices are coefficient planes
+``(n_fsz·n_csz, T)`` (charted: one column per family) or ``(n_fsz·n_csz,
+1)`` (stationary: broadcast scalars). The contraction is a float32
+multiply-accumulate of whole planes on the VPU: a refinement stencil is
+(4×5) at most, far below an MXU tile, and the VPU keeps full f32
+precision. Batch rows (samples, or the orthogonal lines of an N-D axis
+pass) ride the sublanes.
 
-TPU adaptation (DESIGN.md §3): windows are built from *contiguous reshapes*
-plus static row-shifted slices — element ``t·s + k`` (s = n_fsz//2) equals
-``buf.reshape(-1, s)[t + k//s, k % s]`` — so there is NO gather; TPUs hate
-gathers and love static slices. Halo across family blocks is handled by a
-second (shifted) view of the same coarse array, a standard Pallas stencil
-trick that keeps every BlockSpec a plain Blocked map.
+Tiling: a level whose planes fit one block runs as a single block over
+the whole plane (any width). Otherwise blocks of ``b_f`` families (a
+multiple of 128) tile the lanes and the window overhang (``q_max =
+(n_csz-1)//s`` positions) comes from a second, shifted view of the same
+plane array — the next block — so every BlockSpec stays a plain Blocked
+map.
 
-Two variants:
-  * ``_stationary_kernel``  — one shared (n_fsz, n_csz) stencil (regular
-    chart axes, paper Eq. 11–12).
-  * ``_charted_kernel``     — per-family matrices (irregular/charted axes,
-    paper §4.3), a batched small-matmul.
+``noise=False`` (DESIGN.md §10): every N-D per-axis pass except the final
+one injects no excitation, so those launches drop the ξ and sqrt(D)
+operands entirely (forward skips the read and the add, the adjoint skips
+``dxi``).
 
-Both carry arbitrary leading batch dims (chart-invariant axes broadcast,
-paper §4.3 symmetry optimization) and a **batch block** (``batch_block``,
-DESIGN.md §10): the kernel processes ``b_b`` leading-batch rows per grid
-step instead of one, so the stencil matrices are fetched once per family
-block for the whole batch slab and the MXU sees ``b_b``-fold taller GEMMs.
-That is how batched posterior sampling / serving amortizes matrix loads —
-the sample dimension rides *inside* the kernel block, it is not lifted into
-the grid the way a plain ``vmap`` would.
+Dtype policy (DESIGN.md §11): the storage dtype is the dtype of the
+operands — bf16 arrays are read and written as bf16 — and every
+contraction accumulates in float32 (``accum_dtype``), which is also the
+only arithmetic the v5e VPU has.
 
-``noise=False`` mode (DESIGN.md §10): every N-D per-axis pass except the
-final one injects no excitation — its noise factor is pre-contracted into ξ
-outside the kernel — so those passes used to read an all-zeros ξ array from
-HBM for nothing. The noise-free variants drop the ξ and sqrt(D) operands
-entirely (forward skips the read and the add, the adjoint skips the ``dxi``
-computation and its write).
-
-Dtype policy (DESIGN.md §11): every entry point takes ``accum_dtype`` (a
-static dtype name, default ``"float32"``) — the ``preferred_element_type``
-of every MXU contraction and the dtype of the adjoint overlap-add
-accumulator. The *storage* dtype is simply the dtype of the operands: pass
-bf16 arrays and the kernels read/write bf16 HBM while accumulating fp32
-(the ``DtypePolicy`` default of ``repro.kernels.policy``), halving HBM
-bytes per element on every route.
-
-Adjoints (DESIGN.md §9): all entry points carry a ``jax.custom_vjp`` whose
-backward runs hand-written *adjoint* Pallas kernels. The transpose of the
-window-contract is a halo-overlapped scatter-add — coarse element ``t·s + k``
-receives ``Rᵀ g`` contributions from the ≤ ``q_max+1`` families whose window
-covers it — which fuses exactly like the forward: the adjoint kernel reads
-the fine cotangent twice (main + previous-block halo view), contracts on the
-MXU, and overlap-adds via the same static row-shifted slices as
-``_window_cols`` run in reverse. No gather, no atomic, every BlockSpec stays
-a plain Blocked map. Matrix cotangents (∂R, ∂sqrtD) are parameter-sized
-reductions, computed as jnp einsums outside the kernel and *only* when the
-matrices are perturbed (``symbolic_zeros``): fixed-matrix MAP/ADVI inference
-never materializes the window tensor on the backward pass either.
+Adjoints (DESIGN.md §9): every entry point carries a ``jax.custom_vjp``
+whose backward runs a hand-written adjoint kernel. The transpose of the
+window build is an overlap-add: coarse position ``p`` of plane ``r``
+receives ``Rᵀg`` from the families ``p - k//s`` with ``k % s == r`` — the
+same lane-offset slices run in reverse, with the *previous* block as the
+halo view. Matrix cotangents (∂R, ∂sqrtD) are parameter-sized reductions,
+computed as jnp einsums outside the kernel and only when the matrices are
+perturbed (``symbolic_zeros``).
 """
 from __future__ import annotations
 
@@ -68,223 +54,154 @@ import jax
 import jax.numpy as jnp
 from jax.custom_derivatives import SymbolicZero
 
-from .launch import IndexMap, LaunchPlan, OperandSpec, pad_to, run_plan
+from .launch import (
+    IndexMap,
+    LaunchPlan,
+    OperandSpec,
+    pad_to,
+    run_plan,
+    smem_scalars,
+)
+from .planes import axis_step
 from .ref import windows_1d
 
 Array = jnp.ndarray
 
-
-def interpret_default() -> bool:
-    """Pallas interpret mode off-TPU (the shared backend-default predicate
-    for every kernel module)."""
-    return jax.default_backend() != "tpu"
+F32 = jnp.float32
 
 
-def _window_cols(buf: Array, b_f: int, s: int, n_csz: int) -> Array:
-    """(b_b, B_f, n_csz) window matrix from (b_b, >= (B_f + q_max)*s) buffers.
-
-    Element (·, t, k) = buf[·, t*s + k] built with static slices of the
-    (b_b, rows, s) reshape — no gather, no strided access.
-    """
-    q_max = (n_csz - 1) // s
-    resh = buf[:, : (b_f + q_max) * s].reshape(buf.shape[0], b_f + q_max, s)
-    cols = []
-    for k in range(n_csz):
-        q, r = divmod(k, s)
-        cols.append(resh[:, q : q + b_f, r])
-    return jnp.stack(cols, axis=-1)
+def sublane_tile(itemsize: int) -> int:
+    """Rows per (sublane) tile of a dtype: 8 for 32-bit, 16 for 16-bit."""
+    return 8 * max(1, 4 // itemsize)
 
 
-def _stationary_kernel(coarse_ref, halo_ref, xi_ref, r_ref, d_ref, out_ref,
-                       *, b_b: int, b_f: int, s: int, n_csz: int, n_fsz: int,
-                       accum):
-    q_max = (n_csz - 1) // s
-    buf = jnp.concatenate(
-        [coarse_ref[...], halo_ref[:, : q_max * s]], axis=-1
-    )
-    w = _window_cols(buf, b_f, s, n_csz)                  # (b_b, B_f, n_csz)
-    r = r_ref[...]                                        # (n_fsz, n_csz)
-    d = d_ref[...]                                        # (n_fsz, n_fsz)
-    xi = xi_ref[...].reshape(b_b * b_f, n_fsz)
-    fine = jnp.dot(w.reshape(b_b * b_f, n_csz), r.T,
-                   preferred_element_type=accum)
-    fine = fine + jnp.dot(xi, d.T, preferred_element_type=accum)
-    out_ref[...] = fine.reshape(b_b, b_f * n_fsz).astype(out_ref.dtype)
+def _fwd_kernel(*refs, s: int, csz: int, fsz: int, tb: int, halo: bool,
+                noise: bool, charted: bool):
+    it = iter(refs)
+    c_ref = next(it)
+    h_ref = next(it) if halo else None
+    xi_ref = next(it) if noise else None
+    r_ref = next(it)
+    d_ref = next(it) if noise else None
+    o_ref = next(it)
+    x = c_ref[...].astype(F32)                        # (s, b_b, Bl)
+    if halo:
+        x = jnp.concatenate([x, h_ref[...].astype(F32)], axis=-1)
+    y = axis_step(
+        x, pd=0, ud=2, s=s, csz=csz, fsz=fsz, off=0, lout=tb,
+        coef_r=_coef_fn(r_ref, csz, charted=charted),
+        coef_d=_coef_fn(d_ref, fsz, charted=charted) if noise else None,
+        xi=xi_ref[...].astype(F32) if noise else None)
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
-def _stationary_nn_kernel(coarse_ref, halo_ref, r_ref, out_ref,
-                          *, b_b: int, b_f: int, s: int, n_csz: int,
-                          n_fsz: int, accum):
-    """Noise-free stationary forward: no ξ read, no sqrt(D) operand."""
-    q_max = (n_csz - 1) // s
-    buf = jnp.concatenate(
-        [coarse_ref[...], halo_ref[:, : q_max * s]], axis=-1
-    )
-    w = _window_cols(buf, b_f, s, n_csz)
-    fine = jnp.dot(w.reshape(b_b * b_f, n_csz), r_ref[...].T,
-                   preferred_element_type=accum)
-    out_ref[...] = fine.reshape(b_b, b_f * n_fsz).astype(out_ref.dtype)
-
-
-def _charted_kernel(coarse_ref, halo_ref, xi_ref, r_ref, d_ref, out_ref,
-                    *, b_b: int, b_f: int, s: int, n_csz: int, n_fsz: int,
-                    accum):
-    buf = jnp.concatenate(
-        [coarse_ref[...], halo_ref[:, : ((n_csz - 1) // s) * s]], axis=-1
-    )
-    w = _window_cols(buf, b_f, s, n_csz)                  # (b_b, B_f, n_csz)
-    # batched matvec on the MXU, families as the dot_general batch dim,
-    # batch rows as the free dim: matrices are loaded once per family block
-    fine = jnp.einsum("btc,tfc->btf", w, r_ref[...],
-                      preferred_element_type=accum)
-    fine = fine + jnp.einsum("btj,tfj->btf", xi_ref[...], d_ref[...],
-                             preferred_element_type=accum)
-    out_ref[...] = fine.reshape(b_b, b_f * n_fsz).astype(out_ref.dtype)
-
-
-def _charted_nn_kernel(coarse_ref, halo_ref, r_ref, out_ref,
-                       *, b_b: int, b_f: int, s: int, n_csz: int, n_fsz: int,
-                       accum):
-    buf = jnp.concatenate(
-        [coarse_ref[...], halo_ref[:, : ((n_csz - 1) // s) * s]], axis=-1
-    )
-    w = _window_cols(buf, b_f, s, n_csz)
-    fine = jnp.einsum("btc,tfc->btf", w, r_ref[...],
-                      preferred_element_type=accum)
-    out_ref[...] = fine.reshape(b_b, b_f * n_fsz).astype(out_ref.dtype)
-
-
-def _overlap_add_cols(dw: Array, b_f: int, s: int, n_csz: int) -> Array:
-    """(b_b, B_f, s) coarse-cotangent rows from (b_b, B_f + q_max, n_csz)
-    window-cotangent rows — ``_window_cols`` run in reverse.
-
-    dcoarse[t'·s + r] = Σ_q dw[t' − q, q·s + r]: each q-term is the same
-    static row-shifted slice the forward used to *build* column ``q·s + r``,
-    only shifted the other way (``q_max − q`` instead of ``q``). The halo
-    families (previous block's tail) arrive as the leading q_max rows, so the
-    scatter-add across the block boundary is a plain slice — no gather.
-    """
-    q_max = (n_csz - 1) // s
-    b_b = dw.shape[0]
-    acc = jnp.zeros((b_b, b_f, s), dw.dtype)
-    for q in range(q_max + 1):
-        width = min(s, n_csz - q * s)
-        if width <= 0:
-            break
-        piece = dw[:, q_max - q : q_max - q + b_f, q * s : q * s + width]
-        if width < s:
-            piece = jnp.concatenate(
-                [piece, jnp.zeros((b_b, b_f, s - width), piece.dtype)],
-                axis=-1,
-            )
-        acc = acc + piece
-    return acc
-
-
-def _stationary_adjoint_kernel(g_ref, gh_ref, r_ref, d_ref, dc_ref, dxi_ref,
-                               *, b_b: int, b_f: int, s: int, n_csz: int,
-                               n_fsz: int, accum):
-    q_max = (n_csz - 1) // s
-    g = g_ref[...]                                        # (b_b, B_f, n_fsz)
-    r = r_ref[...]
-    d = d_ref[...]
-    g_ext = g
-    if q_max > 0:
-        g_ext = jnp.concatenate([gh_ref[:, b_f - q_max :], g], axis=1)
-    dw = jnp.dot(g_ext.reshape(-1, n_fsz), r,
-                 preferred_element_type=accum)
-    dw = dw.reshape(b_b, b_f + q_max, n_csz)
-    acc = _overlap_add_cols(dw, b_f, s, n_csz)            # (b_b, B_f, s)
-    dc_ref[...] = acc.reshape(b_b, b_f * s).astype(dc_ref.dtype)
-    dxi = jnp.dot(g.reshape(-1, n_fsz), d,
-                  preferred_element_type=accum)
-    dxi_ref[...] = dxi.reshape(b_b, b_f, n_fsz).astype(dxi_ref.dtype)
-
-
-def _stationary_adjoint_nn_kernel(g_ref, gh_ref, r_ref, dc_ref,
-                                  *, b_b: int, b_f: int, s: int, n_csz: int,
-                                  n_fsz: int, accum):
-    """Noise-free adjoint: scatter-add only, no dxi output."""
-    q_max = (n_csz - 1) // s
-    g = g_ref[...]
-    g_ext = g
-    if q_max > 0:
-        g_ext = jnp.concatenate([gh_ref[:, b_f - q_max :], g], axis=1)
-    dw = jnp.dot(g_ext.reshape(-1, n_fsz), r_ref[...],
-                 preferred_element_type=accum)
-    dw = dw.reshape(b_b, b_f + q_max, n_csz)
-    acc = _overlap_add_cols(dw, b_f, s, n_csz)
-    dc_ref[...] = acc.reshape(b_b, b_f * s).astype(dc_ref.dtype)
-
-
-def _charted_adjoint_kernel(g_ref, gh_ref, rm_ref, rh_ref, d_ref,
-                            dc_ref, dxi_ref,
-                            *, b_b: int, b_f: int, s: int, n_csz: int,
-                            n_fsz: int, accum):
-    q_max = (n_csz - 1) // s
-    g = g_ref[...]                                        # (b_b, B_f, n_fsz)
-    # dw[·, t] = R[t]ᵀ g[·, t] — batched matvec, per-family stencils
-    dw = jnp.einsum("btf,tfc->btc", g, rm_ref[...],
-                    preferred_element_type=accum)
-    if q_max > 0:
-        g_h = gh_ref[:, b_f - q_max :]                    # (b_b, q_max, f)
-        r_h = rh_ref[b_f - q_max :]                       # (q_max, f, c)
-        dw_h = jnp.einsum("bqf,qfc->bqc", g_h, r_h,
-                          preferred_element_type=accum)
-        dw = jnp.concatenate([dw_h, dw], axis=1)
-    acc = _overlap_add_cols(dw, b_f, s, n_csz)
-    dc_ref[...] = acc.reshape(b_b, b_f * s).astype(dc_ref.dtype)
-    dxi = jnp.einsum("btf,tfj->btj", g, d_ref[...],
-                     preferred_element_type=accum)
-    dxi_ref[...] = dxi.astype(dxi_ref.dtype)
-
-
-def _charted_adjoint_nn_kernel(g_ref, gh_ref, rm_ref, rh_ref, dc_ref,
-                               *, b_b: int, b_f: int, s: int, n_csz: int,
-                               n_fsz: int, accum):
-    q_max = (n_csz - 1) // s
-    g = g_ref[...]
-    dw = jnp.einsum("btf,tfc->btc", g, rm_ref[...],
-                    preferred_element_type=accum)
-    if q_max > 0:
-        g_h = gh_ref[:, b_f - q_max :]
-        r_h = rh_ref[b_f - q_max :]
-        dw_h = jnp.einsum("bqf,qfc->bqc", g_h, r_h,
-                          preferred_element_type=accum)
-        dw = jnp.concatenate([dw_h, dw], axis=1)
-    acc = _overlap_add_cols(dw, b_f, s, n_csz)
-    dc_ref[...] = acc.reshape(b_b, b_f * s).astype(dc_ref.dtype)
+def _adj_kernel(*refs, s: int, csz: int, fsz: int, tb: int, halo: bool,
+                noise: bool, charted: bool):
+    it = iter(refs)
+    g_ref = next(it)
+    gh_ref = next(it) if halo else None
+    r_ref = next(it)
+    rh_ref = next(it) if (halo and charted) else None
+    d_ref = next(it) if noise else None
+    dc_ref = next(it)
+    dxi_ref = next(it) if noise else None
+    q_max = (csz - 1) // s
+    g = g_ref[...].astype(F32)                        # (fsz, b_b, tb)
+    ext = g
+    if halo:
+        ext = jnp.concatenate([gh_ref[...].astype(F32), g], axis=-1)
+    if charted:
+        r = r_ref[...].astype(F32)
+        if halo:
+            r = jnp.concatenate([rh_ref[...].astype(F32), r], axis=-1)
+        coef = lambda f, k: r[f * csz + k: f * csz + k + 1]  # noqa: E731
+    else:
+        coef = lambda f, k: r_ref[f * csz + k]  # noqa: E731
+    for rr in range(s):
+        acc = None
+        for k in range(rr, csz, s):
+            qq = k // s
+            dw = coef(0, k) * ext[0]
+            for f in range(1, fsz):
+                dw = dw + coef(f, k) * ext[f]
+            if halo:
+                piece = dw[:, tb - qq: 2 * tb - qq]
+            else:
+                parts = [dw]
+                if qq:
+                    parts.insert(0, jnp.zeros(dw.shape[:-1] + (qq,), F32))
+                if q_max - qq:
+                    parts.append(jnp.zeros(dw.shape[:-1] + (q_max - qq,),
+                                           F32))
+                piece = jnp.concatenate(parts, axis=-1) \
+                    if len(parts) > 1 else dw
+            acc = piece if acc is None else acc + piece
+        dc_ref[rr] = acc.astype(dc_ref.dtype)
+    if noise:
+        dcoef = _coef_fn(d_ref, fsz, charted=charted)
+        for j in range(fsz):
+            acc = dcoef(0, j) * g[0]
+            for f in range(1, fsz):
+                acc = acc + dcoef(f, j) * g[f]
+            dxi_ref[j] = acc.astype(dxi_ref.dtype)
 
 
 def halo_floor(n_csz: int, n_fsz: int) -> int:
-    """Minimum family block ``q_max``: the kernels' one-block halo view must
-    cover the window overhang, forward and adjoint alike. The single source
-    of truth for this clamp (dispatch autotune uses it too)."""
+    """Window overhang ``q_max`` in plane positions: a block must be at
+    least this wide for its one-block halo view to cover it (the single
+    source of truth for this clamp; dispatch's autotuners use it too)."""
     s = max(1, n_fsz // 2)
     return (n_csz - 1) // s
 
 
-def _block_shapes(t: int, batch: int, n_csz: int, n_fsz: int,
-                  block_families: int, batch_block: int):
-    s = n_fsz // 2
-    b_f = max(min(block_families, t), halo_floor(n_csz, n_fsz))
-    nblk = -(-t // b_f)  # ceil
-    b_b = max(1, min(batch_block, batch))
+def block_geometry(*, batch: int, t: int, block_families: int,
+                   batch_block: int, itemsize: int):
+    """Legal ``(b_f, nblk, halo, b_b, nbb)`` for a requested tile.
+
+    Lanes: a request covering every family is one block over the whole
+    plane (any width, no halo view); anything smaller rounds up to a
+    multiple of 128 lanes. Sublanes: the batch block is the whole batch
+    or a multiple of the dtype's sublane tile.
+    """
+    if block_families >= t:
+        b_f = t
+    else:
+        b_f = -(-max(block_families, 1) // 128) * 128
+        b_f = t if b_f >= t else b_f
+    nblk = -(-t // b_f)
+    sub = sublane_tile(itemsize)
+    if batch_block >= batch or batch <= sub:
+        b_b = batch
+    else:
+        b_b = -(-max(batch_block, 1) // sub) * sub
+        b_b = batch if b_b >= batch else b_b
     nbb = -(-batch // b_b)
-    return s, b_f, nblk, b_b, nbb
+    return b_f, nblk, nblk > 1, b_b, nbb
 
 
 # Named index maps (DESIGN.md §14): the grid is (family block i, batch
-# block b) with batch innermost, so blocked matrix operands stay VMEM-
+# block b) with batch innermost, so blocked coefficient planes stay VMEM-
 # resident across the whole batch. The names appear verbatim in verifier
 # findings and plan descriptions.
-_IM_BI = IndexMap("(b, i)", lambda i, b: (b, i))
-_IM_BI1 = IndexMap("(b, i + 1)", lambda i, b: (b, i + 1))
-_IM_BI0 = IndexMap("(b, i, 0)", lambda i, b: (b, i, 0))
-_IM_BI10 = IndexMap("(b, i + 1, 0)", lambda i, b: (b, i + 1, 0))
-_IM_00 = IndexMap("(0, 0)", lambda i, b: (0, 0))
-_IM_I00 = IndexMap("(i, 0, 0)", lambda i, b: (i, 0, 0))
-_IM_I100 = IndexMap("(i + 1, 0, 0)", lambda i, b: (i + 1, 0, 0))
+_IM_0BI = IndexMap("(0, b, i)", lambda i, b: (0, b, i))
+_IM_0BI1 = IndexMap("(0, b, i + 1)", lambda i, b: (0, b, i + 1))
+_IM_0I = IndexMap("(0, i)", lambda i, b: (0, i))
+_IM_0I1 = IndexMap("(0, i + 1)", lambda i, b: (0, i + 1))
+
+
+def _coef_spec(name, rows, *, charted, b_f, length, dtype, im):
+    if charted:
+        return OperandSpec(name, (rows, b_f), im, (rows, length), dtype)
+    return smem_scalars(name, rows)
+
+
+def _coef_fn(ref, width, *, charted):
+    """Coefficient accessor: a (1, lanes) row of a charted coefficient
+    plane, or an SMEM scalar of a stationary stencil."""
+    if charted:
+        m = ref[...].astype(F32)
+        return lambda f, k: m[f * width + k: f * width + k + 1]
+    return lambda f, k: ref[f * width + k]
 
 
 def refine_fwd_launch_plan(*, batch: int, t: int, coarse_len: int,
@@ -293,43 +210,43 @@ def refine_fwd_launch_plan(*, batch: int, t: int, coarse_len: int,
                            charted: bool, noise: bool = True) -> LaunchPlan:
     """Declarative launch geometry of one forward 1-D refinement launch.
 
-    This is the single source of truth: the impls pad their operands to
-    the plan's array shapes and :func:`run_plan` builds the pallas_call
-    from it, and ``dispatch.level_launch_plans`` exports the identical
-    record to ``analysis.kernel_verify`` for coverage/bounds proofs.
+    This is the single source of truth: the impls relayout their operands
+    to the plan's array shapes and :func:`run_plan` builds the
+    pallas_call from it, and ``dispatch.level_launch_plans`` exports the
+    identical record to ``analysis.kernel_verify``.
     """
-    s, b_f, nblk, b_b, nbb = _block_shapes(
-        t, batch, n_csz, n_fsz, block_families, batch_block)
-    b_c = b_f * s
+    s = n_fsz // 2
     q_max = (n_csz - 1) // s
-    dtype = jnp.dtype(dtype).name
-    # +1 block: the shifted halo view of the last block must stay in
-    # bounds; round a longer incoming buffer up to whole blocks.
-    l_pad = max((nblk + 1) * b_c, -(-coarse_len // b_c) * b_c)
-    coarse_shape = (nbb * b_b, l_pad)
-    inputs = [
-        OperandSpec("coarse", (b_b, b_c), _IM_BI, coarse_shape, dtype,
-                    overhang=((0, 0), (0, q_max * s))),
-        OperandSpec("coarse_halo", (b_b, b_c), _IM_BI1, coarse_shape, dtype,
-                    halo_of="coarse"),
-    ]
-    if noise:
-        inputs.append(OperandSpec("xi", (b_b, b_f, n_fsz), _IM_BI0,
-                                  (nbb * b_b, nblk * b_f, n_fsz), dtype))
-    if charted:
-        inputs.append(OperandSpec("r", (b_f, n_fsz, n_csz), _IM_I00,
-                                  (nblk * b_f, n_fsz, n_csz), dtype))
-        if noise:
-            inputs.append(OperandSpec("d", (b_f, n_fsz, n_fsz), _IM_I00,
-                                      (nblk * b_f, n_fsz, n_fsz), dtype))
+    dtype = jnp.dtype(dtype)
+    b_f, nblk, halo, b_b, nbb = block_geometry(
+        batch=batch, t=t, block_families=block_families,
+        batch_block=batch_block, itemsize=dtype.itemsize)
+    dtype = dtype.name
+    bp = nbb * b_b
+    tp = nblk * b_f
+    if halo:
+        lp = (nblk + 1) * b_f
+        inputs = [
+            OperandSpec("coarse", (s, b_b, b_f), _IM_0BI, (s, bp, lp), dtype,
+                        overhang=((0, 0), (0, 0), (0, q_max))),
+            OperandSpec("coarse_halo", (s, b_b, b_f), _IM_0BI1, (s, bp, lp),
+                        dtype, halo_of="coarse"),
+        ]
     else:
-        inputs.append(OperandSpec("r", (n_fsz, n_csz), _IM_00,
-                                  (n_fsz, n_csz), dtype))
-        if noise:
-            inputs.append(OperandSpec("d", (n_fsz, n_fsz), _IM_00,
-                                      (n_fsz, n_fsz), dtype))
-    out = OperandSpec("fine", (b_b, b_f * n_fsz), _IM_BI,
-                      (nbb * b_b, nblk * b_f * n_fsz), dtype)
+        lp = t + q_max
+        inputs = [OperandSpec("coarse", (s, b_b, lp), _IM_0BI, (s, bp, lp),
+                              dtype)]
+    if noise:
+        inputs.append(OperandSpec("xi", (n_fsz, b_b, b_f), _IM_0BI,
+                                  (n_fsz, bp, tp), dtype))
+    inputs.append(_coef_spec("r", n_fsz * n_csz, charted=charted, b_f=b_f,
+                             length=tp, dtype=dtype, im=_IM_0I))
+    if noise:
+        inputs.append(_coef_spec("d", n_fsz * n_fsz, charted=charted,
+                                 b_f=b_f, length=tp, dtype=dtype,
+                                 im=_IM_0I))
+    out = OperandSpec("fine", (n_fsz, b_b, b_f), _IM_0BI, (n_fsz, bp, tp),
+                      dtype)
     name = ("charted" if charted else "stationary") + ("" if noise else "_nn")
     return LaunchPlan(
         kernel=f"refine_{name}_fwd", grid=(nblk, nbb),
@@ -337,76 +254,124 @@ def refine_fwd_launch_plan(*, batch: int, t: int, coarse_len: int,
         accum_dtype=jnp.dtype(accum_dtype).name,
         params=dict(kind="fwd", charted=charted, noise=noise, t=t,
                     batch=batch, coarse_len=coarse_len, n_csz=n_csz,
-                    n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk, nbb=nbb),
+                    n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk, nbb=nbb,
+                    halo=halo, lp=lp),
     )
 
 
-_FWD_KERNELS = {
-    (False, True): _stationary_kernel,
-    (False, False): _stationary_nn_kernel,
-    (True, True): _charted_kernel,
-    (True, False): _charted_nn_kernel,
-}
+def _coef_planes(m, t: int, length: int, *, charted: bool, dtype,
+                 front: int = 0):
+    """(T, f, c) per-family matrices -> coefficient planes ``(f·c,
+    length)``; (f, c) shared stencils -> the ``f·c`` float32 scalars."""
+    if not charted:
+        return m.reshape(-1).astype(F32)
+    rows = [m[:, f, k] for f in range(m.shape[1]) for k in range(m.shape[2])]
+    cp = jnp.stack(rows).astype(dtype)
+    return jnp.pad(cp, [(0, 0), (front, length - front - t)])
+
+
+def split_phases(x, s: int):
+    """(..., n) -> (s, ..., n/s) with ``out[r, ..., m] = x[..., m·s + r]``:
+    strided slices, so no array with a tiny minor dimension ever exists
+    in HBM (XLA would pad it to 128 lanes)."""
+    return jnp.stack([x[..., r::s] for r in range(s)])
+
+
+def interleave(planes, length: int):
+    """Inverse of ``split_phases``: (f, ..., n) -> (..., n·f) cropped to
+    ``length``. Strided updates of one output buffer: the TPU compiler
+    handles them at any size, where a stack-and-reshape or an
+    interior-padded sum of billion-element planes does not finish
+    compiling."""
+    f = planes.shape[0]
+    out = jnp.zeros(planes.shape[1:-1] + (planes.shape[-1] * f,),
+                    planes.dtype)
+    for i in range(f):
+        out = out.at[..., i::f].set(planes[i])
+    return out[..., :length]
+
+
+def stack_minor(planes):
+    """(f, ..., n) -> (..., n, f) by per-slot updates (see
+    ``interleave``)."""
+    f = planes.shape[0]
+    out = jnp.zeros(planes.shape[1:] + (f,), planes.dtype)
+    for i in range(f):
+        out = out.at[..., i].set(planes[i])
+    return out
+
+
+def _coarse_planes(coarse, plan: LaunchPlan):
+    """(B, L) halo-padded coarse -> the plan's (s, Bp, Lp) phase planes."""
+    p = plan.params
+    s, lp = p["s"], p["lp"]
+    bp = plan.operand("coarse").array_shape[1]
+    c = pad_to(coarse[:, : s * lp], (bp, s * lp))
+    return split_phases(c, s)
+
+
+def _fine_planes(x, shape, *, front: int = 0):
+    """(B, T, fsz) natural -> (fsz, Bp, Tp) planes, ``front`` zero
+    families prepended."""
+    x = jnp.stack([x[..., f] for f in range(x.shape[-1])])
+    if front:
+        x = jnp.pad(x, [(0, 0), (0, 0), (front, 0)])
+    return pad_to(x, shape)
+
+
+def plan_kernel(plan: LaunchPlan):
+    """The kernel body a 1-D forward or adjoint plan launches."""
+    p = plan.params
+    body = _fwd_kernel if p["kind"] == "fwd" else _adj_kernel
+    return functools.partial(
+        body, s=p["s"], csz=p["n_csz"], fsz=p["n_fsz"], tb=p["b_f"],
+        halo=p["halo"], noise=p["noise"], charted=p["charted"])
 
 
 def _run_fwd(plan: LaunchPlan, coarse, xi, r, d, interpret) -> Array:
     p = plan.params
-    kern = functools.partial(
-        _FWD_KERNELS[(p["charted"], p["noise"])], b_b=p["b_b"], b_f=p["b_f"],
-        s=p["s"], n_csz=p["n_csz"], n_fsz=p["n_fsz"],
-        accum=jnp.dtype(plan.accum_dtype),
-    )
-    coarse = pad_to(coarse, plan.operand("coarse").array_shape)
-    operands = [coarse, coarse]
+    kern = plan_kernel(plan)
+    dtype = jnp.dtype(plan.operand("fine").dtype)
+    tp = plan.operand("fine").array_shape[2]
+    cp = _coarse_planes(coarse.astype(dtype), plan)
+    operands = [cp, cp] if p["halo"] else [cp]
     if p["noise"]:
-        operands.append(pad_to(xi, plan.operand("xi").array_shape))
-    operands.append(pad_to(r, plan.operand("r").array_shape))
+        operands.append(_fine_planes(xi.astype(dtype),
+                                     plan.operand("xi").array_shape))
+    operands.append(_coef_planes(r, p["t"], tp, charted=p["charted"],
+                                 dtype=dtype))
     if p["noise"]:
-        operands.append(pad_to(d, plan.operand("d").array_shape))
+        operands.append(_coef_planes(d, p["t"], tp, charted=p["charted"],
+                                     dtype=dtype))
     out = run_plan(kern, plan, operands, interpret=interpret)
-    return out[: p["batch"], : p["t"] * p["n_fsz"]]
+    return interleave(out[:, : p["batch"]], p["t"] * p["n_fsz"])
 
 
-def _refine_stationary_impl(meta, coarse: Array, xi: Array, r: Array,
-                            d: Array) -> Array:
+def _refine_fwd_impl(charted, meta, coarse: Array, xi: Array, r: Array,
+                     d: Array) -> Array:
     n_csz, n_fsz, block_families, batch_block, interpret, accum_name = meta
     plan = refine_fwd_launch_plan(
         batch=coarse.shape[0], t=xi.shape[-2], coarse_len=coarse.shape[-1],
         n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
         batch_block=batch_block, dtype=coarse.dtype, accum_dtype=accum_name,
-        charted=False)
+        charted=charted)
     return _run_fwd(plan, coarse, xi, r, d, interpret)
 
 
-def _refine_stationary_nn_impl(meta, coarse: Array, r: Array) -> Array:
+def _refine_nn_impl(charted, meta, coarse: Array, r: Array) -> Array:
     t, n_csz, n_fsz, block_families, batch_block, interpret, accum_name = meta
     plan = refine_fwd_launch_plan(
         batch=coarse.shape[0], t=t, coarse_len=coarse.shape[-1],
         n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
         batch_block=batch_block, dtype=coarse.dtype, accum_dtype=accum_name,
-        charted=False, noise=False)
+        charted=charted, noise=False)
     return _run_fwd(plan, coarse, None, r, None, interpret)
 
 
-def _refine_charted_impl(meta, coarse: Array, xi: Array, r: Array,
-                         d: Array) -> Array:
-    n_csz, n_fsz, block_families, batch_block, interpret, accum_name = meta
-    plan = refine_fwd_launch_plan(
-        batch=coarse.shape[0], t=xi.shape[-2], coarse_len=coarse.shape[-1],
-        n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
-        batch_block=batch_block, dtype=coarse.dtype, accum_dtype=accum_name,
-        charted=True)
-    return _run_fwd(plan, coarse, xi, r, d, interpret)
-
-
-def _refine_charted_nn_impl(meta, coarse: Array, r: Array) -> Array:
-    t, n_csz, n_fsz, block_families, batch_block, interpret, accum_name = meta
-    plan = refine_fwd_launch_plan(
-        batch=coarse.shape[0], t=t, coarse_len=coarse.shape[-1],
-        n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
-        batch_block=batch_block, dtype=coarse.dtype, accum_dtype=accum_name,
-        charted=True, noise=False)
-    return _run_fwd(plan, coarse, None, r, None, interpret)
+_refine_stationary_impl = functools.partial(_refine_fwd_impl, False)
+_refine_charted_impl = functools.partial(_refine_fwd_impl, True)
+_refine_stationary_nn_impl = functools.partial(_refine_nn_impl, False)
+_refine_charted_nn_impl = functools.partial(_refine_nn_impl, True)
 
 
 # -- adjoint launches -----------------------------------------------------------
@@ -417,96 +382,104 @@ def refine_adjoint_launch_plan(*, batch: int, t: int, coarse_len: int,
                                ) -> LaunchPlan:
     """Declarative launch geometry of one adjoint (transpose) launch.
 
-    The adjoint flips the halo direction: coarse-block i receives window
-    cotangents from its own g-block plus the *previous* block's tail.
-    Front-padding g by one zero block lets the halo view use index map
-    ``(b, i, 0)`` while the main view uses ``(b, i + 1, 0)`` (in-bounds at
-    i = 0, zero contribution). One extra grid step (nblk + 1) covers the
-    coarse tail the last windows overhang into; its main g-block is the
-    zero back-padding. In the charted variant the halo families' window
-    cotangents need the *previous* block's stencils, so r rides along
-    twice exactly like g (main + shifted view).
+    The adjoint flips the halo direction: coarse block ``i`` receives
+    window cotangents from its own families plus the *previous* block's
+    tail. Front-padding the cotangent planes by one zero block lets the
+    halo view use index map ``(0, b, i)`` while the main view uses
+    ``(0, b, i + 1)``; the grid runs over as many blocks as the coarse
+    planes need (``t + q_max`` positions). Charted coefficient planes
+    ride along twice exactly like the cotangent.
     """
-    s, b_f, nblk, b_b, nbb = _block_shapes(
-        t, batch, n_csz, n_fsz, block_families, batch_block)
-    b_c = b_f * s
+    s = n_fsz // 2
     q_max = (n_csz - 1) // s
-    dtype = jnp.dtype(dtype).name
-    g_shape = (nbb * b_b, (nblk + 2) * b_f, n_fsz)
-    inputs = [
-        OperandSpec("g", (b_b, b_f, n_fsz), _IM_BI10, g_shape, dtype,
-                    overhang=((0, 0), (q_max, 0), (0, 0))),
-        OperandSpec("g_halo", (b_b, b_f, n_fsz), _IM_BI0, g_shape, dtype,
-                    halo_of="g"),
-    ]
-    if charted:
-        r_shape = ((nblk + 2) * b_f, n_fsz, n_csz)
-        inputs.append(OperandSpec("r", (b_f, n_fsz, n_csz), _IM_I100,
-                                  r_shape, dtype,
-                                  overhang=((q_max, 0), (0, 0), (0, 0))))
-        inputs.append(OperandSpec("r_halo", (b_f, n_fsz, n_csz), _IM_I00,
-                                  r_shape, dtype, halo_of="r"))
+    dtype = jnp.dtype(dtype)
+    b_f, nblk, halo, b_b, nbb = block_geometry(
+        batch=batch, t=t, block_families=block_families,
+        batch_block=batch_block, itemsize=dtype.itemsize)
+    dtype = dtype.name
+    bp = nbb * b_b
+    rows_r, rows_d = n_fsz * n_csz, n_fsz * n_fsz
+    if halo:
+        nblk_c = -(-(t + q_max) // b_f)
+        gl = (nblk_c + 1) * b_f
+        inputs = [
+            OperandSpec("g", (n_fsz, b_b, b_f), _IM_0BI1, (n_fsz, bp, gl),
+                        dtype, overhang=((0, 0), (0, 0), (q_max, 0))),
+            OperandSpec("g_halo", (n_fsz, b_b, b_f), _IM_0BI,
+                        (n_fsz, bp, gl), dtype, halo_of="g"),
+        ]
+        if charted:
+            inputs.append(OperandSpec("r", (rows_r, b_f), _IM_0I1,
+                                      (rows_r, gl), dtype,
+                                      overhang=((0, 0), (q_max, 0))))
+            inputs.append(OperandSpec("r_halo", (rows_r, b_f), _IM_0I,
+                                      (rows_r, gl), dtype, halo_of="r"))
+        else:
+            inputs.append(_coef_spec("r", rows_r, charted=False, b_f=b_f,
+                                     length=gl, dtype=dtype, im=_IM_0I))
         if noise:
-            inputs.append(OperandSpec("d", (b_f, n_fsz, n_fsz), _IM_I100,
-                                      ((nblk + 2) * b_f, n_fsz, n_fsz),
-                                      dtype))
+            inputs.append(_coef_spec("d", rows_d, charted=charted, b_f=b_f,
+                                     length=gl, dtype=dtype, im=_IM_0I1))
+        dc_block, lc = (s, b_b, b_f), nblk_c * b_f
+        dxi_shape = (n_fsz, bp, nblk_c * b_f)
+        grid = (nblk_c, nbb)
     else:
-        inputs.append(OperandSpec("r", (n_fsz, n_csz), _IM_00,
-                                  (n_fsz, n_csz), dtype))
+        nblk_c = 1
+        inputs = [OperandSpec("g", (n_fsz, b_b, t), _IM_0BI, (n_fsz, bp, t),
+                              dtype)]
+        inputs.append(_coef_spec("r", rows_r, charted=charted, b_f=t,
+                                 length=t, dtype=dtype, im=_IM_0I))
         if noise:
-            inputs.append(OperandSpec("d", (n_fsz, n_fsz), _IM_00,
-                                      (n_fsz, n_fsz), dtype))
-    outputs = [OperandSpec("dcoarse", (b_b, b_c), _IM_BI,
-                           (nbb * b_b, (nblk + 1) * b_c), dtype)]
+            inputs.append(_coef_spec("d", rows_d, charted=charted, b_f=t,
+                                     length=t, dtype=dtype, im=_IM_0I))
+        dc_block, lc = (s, b_b, t + q_max), t + q_max
+        dxi_shape = (n_fsz, bp, t)
+        grid = (1, nbb)
+    outputs = [OperandSpec("dcoarse", dc_block, _IM_0BI, (s, bp, lc), dtype)]
     if noise:
-        outputs.append(OperandSpec("dxi", (b_b, b_f, n_fsz), _IM_BI0,
-                                   (nbb * b_b, (nblk + 1) * b_f, n_fsz),
-                                   dtype))
+        outputs.append(OperandSpec("dxi", (n_fsz, b_b, b_f), _IM_0BI,
+                                   dxi_shape, dtype))
     name = ("charted" if charted else "stationary") + ("" if noise else "_nn")
     return LaunchPlan(
-        kernel=f"refine_{name}_adjoint", grid=(nblk + 1, nbb),
+        kernel=f"refine_{name}_adjoint", grid=grid,
         inputs=tuple(inputs), outputs=tuple(outputs),
         accum_dtype=jnp.dtype(accum_dtype).name,
         params=dict(kind="bwd", charted=charted, noise=noise, t=t,
                     batch=batch, coarse_len=coarse_len, n_csz=n_csz,
-                    n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk, nbb=nbb),
+                    n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk_c,
+                    nbb=nbb, halo=halo),
     )
-
-
-_ADJ_KERNELS = {
-    (False, True): _stationary_adjoint_kernel,
-    (False, False): _stationary_adjoint_nn_kernel,
-    (True, True): _charted_adjoint_kernel,
-    (True, False): _charted_adjoint_nn_kernel,
-}
 
 
 def _run_adjoint(plan: LaunchPlan, g, r, d, interpret):
     p = plan.params
-    batch, t, b_f, nblk = p["batch"], p["t"], p["b_f"], p["nblk"]
-    kern = functools.partial(
-        _ADJ_KERNELS[(p["charted"], p["noise"])], b_b=p["b_b"], b_f=b_f,
-        s=p["s"], n_csz=p["n_csz"], n_fsz=p["n_fsz"],
-        accum=jnp.dtype(plan.accum_dtype),
-    )
-    # front-pad one zero block (halo at i = 0), back-pad to whole blocks
-    pad_fam = (b_f, (nblk + 1) * b_f - t)
-    g_pad = jnp.pad(g, [(0, p["nbb"] * p["b_b"] - batch), pad_fam, (0, 0)])
-    operands = [g_pad, g_pad]
-    if p["charted"]:
-        r_pad = jnp.pad(r, [pad_fam, (0, 0), (0, 0)])
-        operands += [r_pad, r_pad]
-        if p["noise"]:
-            operands.append(jnp.pad(d, [pad_fam, (0, 0), (0, 0)]))
-    else:
-        operands.append(r)
-        if p["noise"]:
-            operands.append(d)
-    out = run_plan(kern, plan, operands, interpret=interpret)
+    batch, t, b_f, halo = p["batch"], p["t"], p["b_f"], p["halo"]
+    kern = plan_kernel(plan)
+    dtype = jnp.dtype(plan.operand("dcoarse").dtype)
+    g_shape = plan.operand("g").array_shape
+    front = b_f if halo else 0
+    g = g.astype(dtype)
+    gp = split_phases(g, p["n_fsz"])
+    if front:
+        gp = jnp.pad(gp, [(0, 0), (0, 0), (front, 0)])
+    gp = pad_to(gp, g_shape)
+    operands = [gp, gp] if halo else [gp]
+    length = g_shape[2]
+    rp = _coef_planes(r, t, length, charted=p["charted"], dtype=dtype,
+                      front=front)
+    operands += [rp, rp] if (halo and p["charted"]) else [rp]
     if p["noise"]:
-        dc, dxi = out
-        return dc[:batch, :p["coarse_len"]], dxi[:batch, :t]
-    return out[:batch, :p["coarse_len"]]
+        operands.append(_coef_planes(d, t, length, charted=p["charted"],
+                                     dtype=dtype, front=front))
+    out = run_plan(kern, plan, operands, interpret=interpret)
+    dc = out[0] if p["noise"] else out
+    dc = dc[:, :batch]
+    dc = interleave(dc, min(p["coarse_len"], dc.shape[-1] * p["s"]))
+    dc = pad_to(dc, (batch, p["coarse_len"]))
+    if p["noise"]:
+        dxi = out[1][:, :batch, :t]
+        return dc, stack_minor(dxi)
+    return dc
 
 
 @functools.partial(
@@ -524,17 +497,17 @@ def refine_stationary_adjoint_pallas(g: Array, r: Array, d: Array = None, *,
     """Fused adjoint of ``refine_stationary_pallas`` in (coarse, xi).
 
     g: (B, T*n_fsz) fine cotangent -> (dcoarse: (B, coarse_len),
-    dxi: (B, T, n_fsz)). One launch computes both: the halo-overlapped
-    scatter-add of the window cotangents ``g R`` and the noise transpose
-    ``g D`` share the fine-cotangent read. With ``noise=False`` the launch
-    computes (and returns) only ``dcoarse``.
+    dxi: (B, T, n_fsz)). One launch computes both: the overlap-add of the
+    window cotangents ``g R`` and the noise transpose ``g D`` share the
+    cotangent read. With ``noise=False`` the launch computes (and
+    returns) only ``dcoarse``.
     """
     batch = g.shape[0]
-    g = g.reshape(batch, -1, n_fsz)
     plan = refine_adjoint_launch_plan(
-        batch=batch, t=g.shape[-2], coarse_len=coarse_len, n_csz=n_csz,
-        n_fsz=n_fsz, block_families=block_families, batch_block=batch_block,
-        dtype=g.dtype, accum_dtype=accum_dtype, charted=False, noise=noise)
+        batch=batch, t=g.shape[-1] // n_fsz, coarse_len=coarse_len,
+        n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
+        batch_block=batch_block, dtype=g.dtype, accum_dtype=accum_dtype,
+        charted=False, noise=noise)
     return _run_adjoint(plan, g, r, d, interpret)
 
 
@@ -552,15 +525,16 @@ def refine_charted_adjoint_pallas(g: Array, r: Array, d: Array = None, *,
                                   accum_dtype: str = "float32"):
     """Fused adjoint of ``refine_charted_pallas`` (per-family matrices).
 
-    See ``refine_adjoint_launch_plan`` for the halo-flip geometry; r rides
-    along twice exactly like g (main + shifted view).
+    See ``refine_adjoint_launch_plan`` for the halo-flip geometry; the
+    coefficient planes ride along twice exactly like g (main + shifted
+    view).
     """
     batch = g.shape[0]
-    g = g.reshape(batch, -1, n_fsz)
     plan = refine_adjoint_launch_plan(
-        batch=batch, t=g.shape[-2], coarse_len=coarse_len, n_csz=n_csz,
-        n_fsz=n_fsz, block_families=block_families, batch_block=batch_block,
-        dtype=g.dtype, accum_dtype=accum_dtype, charted=True, noise=noise)
+        batch=batch, t=g.shape[-1] // n_fsz, coarse_len=coarse_len,
+        n_csz=n_csz, n_fsz=n_fsz, block_families=block_families,
+        batch_block=batch_block, dtype=g.dtype, accum_dtype=accum_dtype,
+        charted=True, noise=noise)
     return _run_adjoint(plan, g, r, d, interpret)
 
 

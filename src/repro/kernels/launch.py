@@ -30,6 +30,28 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.launch.mesh import target_spec
+
+# Scoped-VMEM limit handed to Mosaic for every compiled launch. The
+# autotuners budget half of the target chip's VMEM for their working-set
+# models (dispatch.VMEM_BUDGET_BYTES); the limit grants three quarters, so
+# a tile the autotuner picks is never refused and the compiler keeps room
+# for its own spills.
+VMEM_LIMIT_BYTES = target_spec().vmem_bytes * 3 // 4
+
+
+def padded_block_elems(block_shape, itemsize: int) -> int:
+    """Elements a block occupies in VMEM: Mosaic tiles the last two dims
+    by (sublanes, 128) with 8 sublanes of 32-bit words (16 for 16-bit)."""
+    shape = tuple(block_shape) or (1,)
+    if len(shape) == 1:
+        shape = (1,) + shape
+    sub = 8 * max(1, 4 // itemsize)
+    rows = -(-shape[-2] // sub) * sub
+    lanes = -(-shape[-1] // 128) * 128
+    return math.prod(shape[:-2]) * rows * lanes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,6 +90,9 @@ class OperandSpec:
     dtype: str
     halo_of: Optional[str] = None
     overhang: Optional[Tuple[Tuple[int, int], ...]] = None
+    # "smem": a small whole-array operand the kernel reads as scalars
+    # (the stationary stencils), resident in scalar memory, never tiled
+    memory_space: str = "vmem"
 
     @property
     def itemsize(self) -> int:
@@ -75,14 +100,27 @@ class OperandSpec:
 
     @property
     def block_bytes(self) -> int:
-        return math.prod(self.block_shape) * self.itemsize
+        """VMEM bytes of one block, tile padding included."""
+        if self.memory_space == "smem":
+            return 0
+        return padded_block_elems(self.block_shape, self.itemsize) \
+            * self.itemsize
 
     @property
     def array_bytes(self) -> int:
         return math.prod(self.array_shape) * self.itemsize
 
     def block_spec(self) -> pl.BlockSpec:
+        if self.memory_space == "smem":
+            return pl.BlockSpec(memory_space=pltpu.SMEM)
         return pl.BlockSpec(self.block_shape, self.index_map.fn)
+
+
+def smem_scalars(name: str, n: int) -> "OperandSpec":
+    """Whole-array float32 scalar table in SMEM (``n`` entries)."""
+    zeros = (0,)
+    return OperandSpec(name, (n,), IndexMap("(0)", lambda *g: zeros),
+                       (n,), "float32", memory_space="smem")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,6 +158,8 @@ class LaunchPlan:
                 d["halo_of"] = op.halo_of
             if op.overhang:
                 d["overhang"] = [list(p) for p in op.overhang]
+            if op.memory_space != "vmem":
+                d["memory_space"] = op.memory_space
             return d
 
         return {
@@ -179,5 +219,8 @@ def run_plan(kern, plan: LaunchPlan, operands, *, interpret: bool):
         out_specs=out_specs[0] if single else out_specs,
         out_shape=out_shape[0] if single else out_shape,
         interpret=interpret,
+        compiler_params=(None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)),
+        name=plan.kernel,
     )
     return call(*operands)

@@ -48,18 +48,14 @@ import jax.numpy as jnp
 
 from repro.core.refine import LevelGeom
 
-from .icr_refine import (
-    interpret_default as _interpret_default,
-    refine_charted_pallas,
-    refine_stationary_pallas,
-)
+from .icr_refine import refine_charted_pallas, refine_stationary_pallas
 from .nd_fused import precontract_noise
 
 Array = jnp.ndarray
 
 
 def refine_axes(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
-                interpret: bool | None = None,
+                interpret: bool,
                 block_families: int | None = None,
                 sample_axis: bool = False,
                 accum_dtype: str = "float32") -> Array:
@@ -74,12 +70,11 @@ def refine_axes(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
     contraction (in-kernel and the ξ pre-contraction here) accumulates in
     ``accum_dtype`` (DESIGN.md §11).
     """
-    from .dispatch import autotune_block_families  # lazy: avoid import cycle
+    from .dispatch import axis_pass_tiles  # lazy: avoid import cycle
 
     nd = len(geom.coarse_shape)
     fsz, csz, b = geom.n_fsz, geom.n_csz, geom.b
     T = tuple(geom.T)
-    interpret = _interpret_default() if interpret is None else interpret
     accum = jnp.dtype(accum_dtype)
     off = 1 if sample_axis else 0
     lead = field.shape[:off]
@@ -104,15 +99,15 @@ def refine_axes(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
         if ag.boundary == "reflect":
             coarse = jnp.pad(coarse, [(0, 0), (b, b)], mode="reflect")
         charted = rs[a].ndim == 3
-        bf = block_families or autotune_block_families(
-            ag.T[0], csz, fsz, charted=charted,
-            itemsize=jnp.dtype(field.dtype).itemsize,
-        )
+        bf, bb = axis_pass_tiles(coarse.shape[0], ag.T[0], csz, fsz,
+                                 charted=charted,
+                                 itemsize=jnp.dtype(field.dtype).itemsize)
+        bf = block_families or bf
         kern = refine_charted_pallas if charted else refine_stationary_pallas
         if a == 0:
             res = kern(
                 coarse, xi0, rs[a], ds[a], n_csz=csz, n_fsz=fsz,
-                block_families=bf, interpret=interpret,
+                block_families=bf, batch_block=bb, interpret=interpret,
                 accum_dtype=accum_dtype,
             )
         else:
@@ -120,7 +115,8 @@ def refine_axes(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
             # (no zero-excitation array is ever built or read)
             res = kern(
                 coarse, None, rs[a], None, n_csz=csz, n_fsz=fsz,
-                block_families=bf, interpret=interpret, noise=False,
+                block_families=bf, batch_block=bb, interpret=interpret,
+                noise=False,
                 t=ag.T[0], accum_dtype=accum_dtype,
             )
         out = jnp.moveaxis(res.reshape(bshape + (T[a] * fsz,)), -1, off + a)

@@ -10,8 +10,9 @@ collapses a whole level into ONE ``pallas_call``:
     (halo via the second-shifted-view trick, DESIGN.md §3) times the FULL
     extent of every trailing axis — into VMEM,
   * performs all ``d`` per-axis Kronecker contractions back-to-back in
-    VMEM/VREGs (window build per axis is the same contiguous-reshape +
-    static-row-shift trick as the 1-D kernels: no gather, no strided loads),
+    VMEM on a phase-plane tile (``planes.py``: every axis split into
+    ``s`` phases outside the kernel, so a window along any axis is an
+    offset slice — no gather, no strided access),
   * adds the correlated noise ``sqrt(D_0) ξ`` (the noise factors of axes
     ``1..d-1`` are pre-contracted into ξ outside, exactly like
     ``nd.refine_axes``), and
@@ -21,7 +22,8 @@ HBM traffic per level drops from ``d·(read+write N)`` plus relayouts to
 ``read L + read ξ + write N`` — the 1-D kernel's traffic equation, now at
 any dimensionality (``roofline.level_traffic`` carries the model).
 
-Tiling is along axis 0 only; the trailing axes ride whole inside the tile.
+Tiling is along axis 0 only; the trailing axes ride whole inside the tile,
+the longest of them on the lanes.
 When the joint tile + halos exceed the VMEM budget the dispatch layer falls
 back to the per-axis passes (``dispatch.autotune_nd_fused`` returns None —
 the fallback rule of DESIGN.md §10).
@@ -43,7 +45,6 @@ from __future__ import annotations
 
 import functools
 
-import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.custom_derivatives import SymbolicZero
@@ -51,11 +52,18 @@ from jax.custom_derivatives import SymbolicZero
 from repro.core.refine import LevelGeom
 
 from .icr_refine import (
-    interpret_default as _interpret_default,
     refine_charted_adjoint_pallas,
     refine_stationary_adjoint_pallas,
 )
-from .launch import IndexMap, LaunchPlan, OperandSpec, pad_to, run_plan
+from .launch import (
+    IndexMap,
+    LaunchPlan,
+    OperandSpec,
+    pad_to,
+    run_plan,
+    smem_scalars,
+)
+from .planes import axis_step, fine_to_planes, from_planes, to_planes
 from .ref import windows_1d
 
 Array = jnp.ndarray
@@ -115,51 +123,76 @@ def _contract_windows(w: Array, r: Array, ax: int, *, merge: bool = True,
     return out
 
 
-# -- the megakernel body --------------------------------------------------------
+# -- the megakernel -------------------------------------------------------------
+def fused_layout(nd: int, lq_trail) -> tuple:
+    """Position-dim order of the megakernel tile: axis 0 (the tiled axis)
+    first, then the trailing axes by plane length, longest on the lanes."""
+    trail = sorted(range(1, nd), key=lambda a: lq_trail[a - 1])
+    return (0,) + tuple(trail)
+
+
+def fused_block(nd: int, t0: int, b_f: int, itemsize: int) -> int:
+    """Legal axis-0 family block: the whole axis, or (on a 2-D tile, where
+    axis 0 rides the sublanes) a multiple of the sublane tile."""
+    if b_f >= t0:
+        return t0
+    if nd == 2:
+        sub = 8 * max(1, 4 // itemsize)
+        b_f = -(-b_f // sub) * sub
+        return t0 if b_f >= t0 else b_f
+    return b_f
+
+
+def _coef_shape(nd: int, layout, a: int, n: int) -> tuple:
+    """Broadcast shape of axis ``a``'s coefficient planes in the tile:
+    ``n`` families on axis a's position dim (1 when stationary)."""
+    shape = [1] * (2 * nd + 1)
+    shape[nd + 1 + list(layout).index(a)] = n
+    return tuple(shape)
+
+
+def _coef_fn(ref, width, charted):
+    """Coefficient accessor of one axis: a float32 broadcast plane of a
+    charted factor (kept f32 in HBM: Mosaic broadcasts a value with unit
+    sublane and lane dims only from its own f32 layout), or an SMEM
+    scalar of a stationary one."""
+    if charted:
+        m = ref[...]
+        return lambda f, k: m[f * width + k]
+    return lambda f, k: ref[f * width + k]
+
+
 def _nd_fused_kernel(*refs, nd: int, csz: int, fsz: int, T: tuple,
-                     charted: tuple, b_f: int, s_b: int, accum):
-    coarse_ref, halo_ref, xi_ref, r0_ref, d0_ref = refs[:5]
-    rt_refs = refs[5 : 5 + nd - 1]
-    out_ref = refs[-1]
+                     layout: tuple, b_f: int, halo: bool, charted: tuple):
+    it = iter(refs)
+    c_ref = next(it)
+    h_ref = next(it) if halo else None
+    xi_ref = next(it)
+    r0_ref = next(it)
+    d0_ref = next(it)
+    rt_refs = [next(it) for _ in range(nd - 1)]
+    out_ref = next(it)
     s = fsz // 2
-    q_max = (csz - 1) // s
-
-    x = jnp.concatenate([coarse_ref[...], halo_ref[:, : q_max * s]], axis=1)
-    # (s_b, (b_f + q_max)*s, *Lp_trail) — all d contractions happen on this
-    # tile in VMEM; nothing intermediate ever goes back to HBM.
+    ud = [nd + 1 + list(layout).index(a) for a in range(nd)]
+    x = c_ref[...].astype(jnp.float32)
+    if halo:
+        x = jnp.concatenate([x, h_ref[...].astype(jnp.float32)], axis=ud[0])
+    # all d contractions on this tile in VMEM; nothing intermediate ever
+    # goes back to HBM
     for a in range(nd - 1, 0, -1):
-        ax = 1 + a
-        x = _slice_axis(x, ax, (T[a] + q_max) * s)
-        w = _axis_windows(x, ax, T[a], s, csz)
-        x = _contract_windows(w, rt_refs[a - 1][...], ax, accum=accum)
-
-    w0 = _axis_windows(x, 1, b_f, s, csz)          # (s_b, b_f, csz, *F_trail)
-    fine = _contract_windows(w0, r0_ref[...], 1, merge=False, accum=accum)
-    prod_f = int(np.prod(fine.shape[3:])) if nd > 1 else 1
-    fine = fine.reshape(s_b, b_f, fsz, prod_f)
-
-    xi = xi_ref[...].reshape(s_b, b_f, fsz, prod_f)
-    d0 = d0_ref[...]
-    if d0.ndim == 2:
-        fine = fine + jnp.einsum("sbjp,fj->sbfp", xi, d0,
-                                 preferred_element_type=accum)
-    else:
-        fine = fine + jnp.einsum("sbjp,bfj->sbfp", xi, d0,
-                                 preferred_element_type=accum)
-    out_ref[...] = fine.reshape(s_b, b_f * fsz, prod_f).astype(out_ref.dtype)
-
-
-# Named index maps — grid (family block i, sample block b), samples
-# innermost so the blocked matrices stay VMEM-resident (DESIGN.md §14).
-_IM_BI0 = IndexMap("(b, i, 0)", lambda i, b: (b, i, 0))
-_IM_00 = IndexMap("(0, 0)", lambda i, b: (0, 0))
-_IM_000 = IndexMap("(0, 0, 0)", lambda i, b: (0, 0, 0))
-_IM_I00 = IndexMap("(i, 0, 0)", lambda i, b: (i, 0, 0))
+        x = axis_step(x, pd=a, ud=ud[a], s=s, csz=csz, fsz=fsz, off=0,
+                      lout=T[a],
+                      coef_r=_coef_fn(rt_refs[a - 1], csz, charted[a]))
+    x = axis_step(x, pd=0, ud=ud[0], s=s, csz=csz, fsz=fsz, off=0,
+                  lout=b_f, coef_r=_coef_fn(r0_ref, csz, charted[0]),
+                  coef_d=_coef_fn(d0_ref, fsz, charted[0]),
+                  xi=xi_ref[...].astype(jnp.float32))
+    out_ref[...] = x.astype(out_ref.dtype)
 
 
 def fused_launch_shapes(geom: LevelGeom, *, samples: int, b_f: int,
                         s_b: int) -> dict:
-    """Padded operand extents of one megakernel launch.
+    """Padded operand extents of one megakernel launch, natural layout.
 
     The single source of truth shared by ``refine_nd_fused``'s padding and
     the geom-level plan export (``dispatch.level_launch_plans``) — the
@@ -172,8 +205,9 @@ def fused_launch_shapes(geom: LevelGeom, *, samples: int, b_f: int,
     q_max = (csz - 1) // s
     T = tuple(geom.T)
     pad = 2 * b if geom.boundary == "reflect" else 0
-    lp_trail = tuple(max(geom.coarse_shape[a] + pad, (T[a] + q_max) * s)
-                     for a in range(1, nd))
+    lp_trail = tuple(
+        -(-max(geom.coarse_shape[a] + pad, (T[a] + q_max) * s) // s) * s
+        for a in range(1, nd))
     nblk = -(-T[0] // b_f)
     l0 = geom.coarse_shape[0] + pad
     nblk2 = max(nblk + 1, -(-l0 // (b_f * s)))
@@ -189,70 +223,136 @@ def nd_fused_launch_plan(*, nd: int, csz: int, fsz: int, T: tuple,
                          charted: tuple, b_f: int, s_b: int, sp: int,
                          l0p: int, lp_trail: tuple, nblk: int, prod_f: int,
                          dtype, accum_dtype) -> LaunchPlan:
-    """Declarative launch geometry of one fused N-D level launch."""
+    """Declarative launch geometry of one fused N-D level launch.
+
+    The tile is the phase-plane layout of ``planes.py``: ``s`` phases per
+    axis, the sample block, then the positions with axis 0 (blocked by
+    ``b_f`` families, halo from the next block) first and the longest
+    trailing axis on the lanes.
+    """
     s = fsz // 2
     q_max = (csz - 1) // s
     nbs = sp // s_b
     dtype = jnp.dtype(dtype).name
-    zeros_t = (0,) * (nd - 1)
-    trail = ", 0" * (nd - 1)
-    im_main = IndexMap(f"(b, i{trail})", lambda i, b: (b, i) + zeros_t)
-    im_halo = IndexMap(f"(b, i + 1{trail})",
-                       lambda i, b: (b, i + 1) + zeros_t)
-    field_shape = (sp, l0p) + tuple(lp_trail)
-    field_blk = (s_b, b_f * s) + tuple(lp_trail)
-    inputs = [
-        OperandSpec("field", field_blk, im_main, field_shape, dtype,
-                    overhang=((0, 0), (0, q_max * s)) + ((0, 0),) * (nd - 1)),
-        OperandSpec("field_halo", field_blk, im_halo, field_shape, dtype,
-                    halo_of="field"),
-        OperandSpec("xi", (s_b, b_f * fsz, prod_f), _IM_BI0,
-                    (sp, nblk * b_f * fsz, prod_f), dtype),
-    ]
-    if charted[0]:
-        inputs.append(OperandSpec("r0", (b_f, fsz, csz), _IM_I00,
-                                  (nblk * b_f, fsz, csz), dtype))
-        inputs.append(OperandSpec("d0", (b_f, fsz, fsz), _IM_I00,
-                                  (nblk * b_f, fsz, fsz), dtype))
+    lq_trail = tuple(n // s for n in lp_trail)
+    layout = fused_layout(nd, lq_trail)
+    halo = nblk > 1
+    pos_in = {0: None, **{a: lq_trail[a - 1] for a in range(1, nd)}}
+    pos_out = {a: T[a] for a in range(nd)}
+    l0q = (nblk + 1) * b_f if halo else T[0] + q_max
+    pos_in[0] = l0q
+    pos_out[0] = nblk * b_f
+    trail_in = tuple(pos_in[a] for a in layout[1:])
+    trail_out = tuple(pos_out[a] for a in layout[1:])
+    zeros = (0,) * (nd - 1)
+
+    def im(name, shift=0):
+        return IndexMap(f"({', '.join(['0'] * nd)}, b, i{name}"
+                        f"{', 0' * (nd - 1)})",
+                        lambda i, b, _s=shift: (0,) * nd + (b, i + _s)
+                        + zeros)
+
+    im_main, im_halo = im(""), im(" + 1", 1)
+    blk0 = b_f if halo else l0q
+    field_shape = (s,) * nd + (sp, l0q) + trail_in
+    field_blk = (s,) * nd + (s_b, blk0) + trail_in
+    if halo:
+        over = [(0, 0)] * (2 * nd + 1)
+        over[nd + 1] = (0, q_max)
+        inputs = [
+            OperandSpec("field", field_blk, im_main, field_shape, dtype,
+                        overhang=tuple(over)),
+            OperandSpec("field_halo", field_blk, im_halo, field_shape, dtype,
+                        halo_of="field"),
+        ]
     else:
-        inputs.append(OperandSpec("r0", (fsz, csz), _IM_00,
-                                  (fsz, csz), dtype))
-        inputs.append(OperandSpec("d0", (fsz, fsz), _IM_00,
-                                  (fsz, fsz), dtype))
+        inputs = [OperandSpec("field", field_blk, im_main, field_shape,
+                              dtype)]
+    out_shape = (fsz,) * nd + (sp, nblk * b_f) + trail_out
+    out_blk = (fsz,) * nd + (s_b, b_f) + trail_out
+    inputs.append(OperandSpec("xi", out_blk, im_main, out_shape, dtype))
+    rank = 2 * nd + 1
+    for name, rows in (("r0", fsz * csz), ("d0", fsz * fsz)):
+        if charted[0]:
+            cshape = (rows,) + _coef_shape(nd, layout, 0, nblk * b_f)
+            cblk = (rows,) + _coef_shape(nd, layout, 0, b_f)
+            cim = IndexMap("(0, ..., i, ...)",
+                           lambda i, b, _r=rank: (0,) * (_r - nd + 1)
+                           + (i,) + (0,) * (nd - 1))
+            inputs.append(OperandSpec(name, cblk, cim, cshape, "float32"))
+        else:
+            inputs.append(smem_scalars(name, rows))
     for a in range(1, nd):
         if charted[a]:
-            inputs.append(OperandSpec(f"r{a}", (T[a], fsz, csz), _IM_000,
-                                      (T[a], fsz, csz), dtype))
+            cshape = (fsz * csz,) + _coef_shape(nd, layout, a, T[a])
+            inputs.append(OperandSpec(
+                f"r{a}", cshape,
+                IndexMap("(0, ..., 0)",
+                         lambda i, b, _r=rank: (0,) * (_r + 1)),
+                cshape, "float32"))
         else:
-            inputs.append(OperandSpec(f"r{a}", (fsz, csz), _IM_00,
-                                      (fsz, csz), dtype))
-    out = OperandSpec("fine", (s_b, b_f * fsz, prod_f), _IM_BI0,
-                      (sp, nblk * b_f * fsz, prod_f), dtype)
+            inputs.append(smem_scalars(f"r{a}", fsz * csz))
+    out = OperandSpec("fine", out_blk, im_main, out_shape, dtype)
     return LaunchPlan(
         kernel="refine_nd_fused", grid=(nblk, nbs),
         inputs=tuple(inputs), outputs=(out,),
         accum_dtype=jnp.dtype(accum_dtype).name,
         params=dict(kind="fwd", nd=nd, csz=csz, fsz=fsz, T=tuple(T),
                     charted=tuple(charted), s=s, b_f=b_f, s_b=s_b,
-                    nblk=nblk, nbs=nbs, l0p=l0p,
-                    lp_trail=tuple(lp_trail), prod_f=prod_f),
+                    nblk=nblk, nbs=nbs, l0p=l0p, halo=halo,
+                    layout=layout, lp_trail=tuple(lp_trail),
+                    prod_f=prod_f),
     )
+
+
+def plan_kernel(plan: LaunchPlan):
+    """The kernel body a megakernel plan launches."""
+    p = plan.params
+    return functools.partial(
+        _nd_fused_kernel, nd=p["nd"], csz=p["csz"], fsz=p["fsz"], T=p["T"],
+        layout=p["layout"], b_f=p["b_f"], halo=p["halo"],
+        charted=p["charted"])
+
+
+def _fused_coef(m, nd: int, layout, a: int, n: int, *, charted: bool):
+    """Per-axis factor -> coefficient planes in the tile's broadcast
+    shape (families on axis a's position dim, zero-padded to ``n``)."""
+    if not charted:
+        return m.reshape(-1).astype(jnp.float32)
+    t = m.shape[0]
+    cp = jnp.pad(m.reshape(t, -1).T, [(0, 0), (0, n - t)])
+    return cp.reshape((cp.shape[0],) + _coef_shape(nd, layout, a, n)) \
+        .astype(jnp.float32)
 
 
 def _nd_fused_impl(meta, field: Array, xi0: Array, r0: Array, d0: Array,
                    rts: tuple) -> Array:
     nd, csz, fsz, T, charted, b_f, s_b, interpret, accum_name = meta
+    s = fsz // 2
+    t0p = xi0.shape[1] // fsz
     plan = nd_fused_launch_plan(
         nd=nd, csz=csz, fsz=fsz, T=T, charted=charted, b_f=b_f, s_b=s_b,
         sp=field.shape[0], l0p=field.shape[1], lp_trail=field.shape[2:],
-        nblk=xi0.shape[1] // (b_f * fsz), prod_f=xi0.shape[2],
+        nblk=t0p // b_f, prod_f=xi0.shape[2],
         dtype=field.dtype, accum_dtype=accum_name)
-    kern = functools.partial(
-        _nd_fused_kernel, nd=nd, csz=csz, fsz=fsz, T=T, charted=charted,
-        b_f=b_f, s_b=s_b, accum=jnp.dtype(accum_name),
-    )
-    return run_plan(kern, plan, (field, field, xi0, r0, d0, *rts),
-                    interpret=interpret)
+    p = plan.params
+    layout = p["layout"]
+    dt = field.dtype
+    l0q = plan.operand("field").array_shape[nd + 1]
+    lens = (l0q,) + tuple(n // s for n in field.shape[2:])
+    fp = to_planes(field, lens, s=s, lead=1, layout=layout)
+    t_full = (t0p,) + tuple(T[1:])
+    xp = fine_to_planes(xi0.astype(dt), t_full, fsz=fsz, layout=layout)
+    ops = [fp, fp] if p["halo"] else [fp]
+    ops.append(xp)
+    ops.append(_fused_coef(r0, nd, layout, 0, t0p, charted=charted[0]))
+    ops.append(_fused_coef(d0, nd, layout, 0, t0p, charted=charted[0]))
+    for a in range(1, nd):
+        ops.append(_fused_coef(rts[a - 1], nd, layout, a, T[a],
+                               charted=charted[a]))
+    out = run_plan(plan_kernel(plan), plan, ops, interpret=interpret)
+    out = from_planes(out, layout=layout)
+    return out.reshape(out.shape[0], t0p * fsz, -1)
 
 
 def _nd_fused_ref(meta, field: Array, xi0: Array, r0: Array, d0: Array,
@@ -327,28 +427,51 @@ def _core_bwd(meta, res, g):
         return vjp(g)
 
     # fixed matrices: compose the 1-D adjoint kernels in reverse axis order.
-    from .dispatch import autotune_block_families  # lazy: import cycle
+    df, dxi = axes_adjoint(
+        g, r0, d0, rts, T=T, csz=csz, fsz=fsz, coarse_lens=field.shape[1:],
+        charted=charted, interpret=interpret, accum_dtype=accum_name)
+    return (df.astype(field.dtype), dxi.astype(xi0.dtype), zeros[2],
+            zeros[3], zeros[4])
 
+
+def axes_adjoint(g: Array, r0: Array, d0: Array, rts, *, T: tuple, csz: int,
+                 fsz: int, coarse_lens: tuple, charted: tuple, interpret,
+                 accum_dtype: str):
+    """Transpose of one N-D level at fixed matrices, as 1-D adjoint kernels
+    in reverse axis order: the forward contracts the trailing axes
+    (noise-free) and then axis 0 (with the noise add), so the adjoint runs
+    axis 0 first — ``dξ`` shares its fine-cotangent read — then axes
+    1..d-1 with ``noise=False``.
+
+    g: (S, T0·fsz, prod_f), ``T0 = g.shape[1] // fsz`` families along axis
+    0 (padded ones included); ``coarse_lens``: the forward's coarse extent
+    per axis (the tail past a trailing axis's window reach gets zero).
+    Returns ``(dfield (S, *coarse_lens), dξ0 (S, T0·fsz, prod_f))`` in the
+    cotangent's dtype. Shared by the megakernel and the pyramid backward.
+    """
+    from .dispatch import axis_pass_tiles  # lazy: import cycle
+
+    nd = len(T)
     s = fsz // 2
     q_max = (csz - 1) // s
-    sp = field.shape[0]
-    l0p = field.shape[1]
-    lp_trail = field.shape[2:]
-    t0p = xi0.shape[1] // fsz
-    prod_f = xi0.shape[2]
+    sp = g.shape[0]
+    l0p, lp_trail = coarse_lens[0], tuple(coarse_lens[1:])
+    t0p = g.shape[1] // fsz
+    prod_f = g.shape[2]
     f_trail = tuple(T[a] * fsz for a in range(1, nd))
+    itemsize = jnp.dtype(g.dtype).itemsize
 
     # axis-0 adjoint (with noise: dxi shares the fine-cotangent read)
-    gb = g.reshape(sp, t0p * fsz, prod_f)
-    gb = jnp.moveaxis(gb, 1, -1).reshape(sp * prod_f, t0p * fsz)
-    bf0 = autotune_block_families(t0p, csz, fsz, charted=charted[0])
+    gb = jnp.moveaxis(g, 1, -1).reshape(sp * prod_f, t0p * fsz)
+    bf0, bb0 = axis_pass_tiles(gb.shape[0], t0p, csz, fsz,
+                               charted=charted[0], itemsize=itemsize)
     adj0 = (refine_charted_adjoint_pallas if charted[0]
             else refine_stationary_adjoint_pallas)
     dc0, dxi0 = adj0(gb, r0, d0, coarse_len=l0p, n_csz=csz, n_fsz=fsz,
-                     block_families=bf0, interpret=interpret,
-                     accum_dtype=accum_name)
+                     block_families=bf0, batch_block=bb0,
+                     interpret=interpret, accum_dtype=accum_dtype)
     dxi = dxi0.reshape(sp, prod_f, t0p, fsz).transpose(0, 2, 3, 1)
-    dxi = dxi.reshape(sp, t0p * fsz, prod_f).astype(xi0.dtype)
+    dxi = dxi.reshape(sp, t0p * fsz, prod_f)
     cur = dc0.reshape((sp,) + f_trail + (l0p,))
     cur = jnp.moveaxis(cur, -1, 1)                # (sp, L0p, *F_trail)
 
@@ -358,18 +481,18 @@ def _core_bwd(meta, res, g):
         arr = jnp.moveaxis(cur, ax, -1)
         bshape = arr.shape[:-1]
         g_a = arr.reshape(-1, T[a] * fsz)
-        bf_a = autotune_block_families(T[a], csz, fsz, charted=charted[a])
+        bf_a, bb_a = axis_pass_tiles(g_a.shape[0], T[a], csz, fsz,
+                                     charted=charted[a], itemsize=itemsize)
         adj = (refine_charted_adjoint_pallas if charted[a]
                else refine_stationary_adjoint_pallas)
         used = (T[a] + q_max) * s
         dca = adj(g_a, rts[a - 1], coarse_len=used, n_csz=csz, n_fsz=fsz,
-                  block_families=bf_a, interpret=interpret, noise=False,
-                  accum_dtype=accum_name)
+                  block_families=bf_a, batch_block=bb_a,
+                  interpret=interpret, noise=False, accum_dtype=accum_dtype)
         if lp_trail[a - 1] > used:  # tail the forward's tile slice dropped
             dca = jnp.pad(dca, [(0, 0), (0, lp_trail[a - 1] - used)])
         cur = jnp.moveaxis(dca.reshape(bshape + (lp_trail[a - 1],)), -1, ax)
-
-    return (cur.astype(field.dtype), dxi, zeros[2], zeros[3], zeros[4])
+    return cur, dxi
 
 
 _nd_fused_core.defvjp(_core_fwd, _core_bwd, symbolic_zeros=True)
@@ -385,12 +508,9 @@ def precontract_noise(xi_nd, ds, *, off: int, accum) -> Array:
     nd = (xi_nd.ndim - off) // 2
     for a in range(1, nd):
         x2 = jnp.moveaxis(xi_nd, (off + a, off + nd + a), (-2, -1))
-        if ds[a].ndim == 2:
-            x2 = jnp.einsum("...tj,fj->...tf", x2, ds[a],
-                            preferred_element_type=accum)
-        else:
-            x2 = jnp.einsum("...tj,tfj->...tf", x2, ds[a],
-                            preferred_element_type=accum)
+        eq = "...tj,fj->...tf" if ds[a].ndim == 2 else "...tj,tfj->...tf"
+        x2 = jnp.einsum(eq, x2, ds[a], preferred_element_type=accum,
+                        precision=jax.lax.Precision.HIGHEST)
         xi_nd = jnp.moveaxis(x2, (-2, -1), (off + a, off + nd + a))
     return xi_nd
 
@@ -411,7 +531,7 @@ def prepare_xi0(xi: Array, ds, T: tuple, fsz: int, *, accum,
 
 # -- public wrapper -------------------------------------------------------------
 def refine_nd_fused(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
-                    interpret: bool | None = None,
+                    interpret: bool,
                     block_families: int | None = None,
                     sample_block: int | None = None,
                     sample_axis: bool = False,
@@ -437,7 +557,6 @@ def refine_nd_fused(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
     q_max = (csz - 1) // s
     T = tuple(geom.T)
     charted = tuple(rs[a].ndim == 3 for a in range(nd))
-    interpret = _interpret_default() if interpret is None else interpret
     accum = jnp.dtype(accum_dtype)
 
     if not sample_axis:
@@ -454,6 +573,7 @@ def refine_nd_fused(field: Array, xi: Array, rs, ds, geom: LevelGeom, *,
     b_f, s_b = blocks
     if block_families is not None:
         b_f = max(min(block_families, T[0]), q_max, 1)
+    b_f = fused_block(nd, T[0], b_f, jnp.dtype(field.dtype).itemsize)
     if sample_block is not None:
         s_b = max(1, min(sample_block, n_s))
 

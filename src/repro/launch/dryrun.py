@@ -193,6 +193,9 @@ def _run_in_subprocess(cell_id: str, timeout: int = 3600) -> dict:
            "--json-only"]
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    # a CPU-only tool: the cells compile on virtual host devices, and a
+    # child must never reach for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         out = subprocess.run(cmd, capture_output=True, text=True,
                              timeout=timeout, env=env)

@@ -17,6 +17,7 @@ Mesh layouts (TPU v5e pod = 16x16 = 256 chips):
 """
 from __future__ import annotations
 
+import dataclasses
 
 import jax
 import numpy as np
@@ -58,10 +59,54 @@ def make_host_mesh(model: int | None = None) -> Mesh:
     return _make((n // model, model), ("data", "model"))
 
 
-# -- hardware constants (TPU v5e, per chip) -----------------------------------
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s
-HBM_BW = 819e9               # B/s
-ICI_BW = 50e9                # B/s per link (~per-device usable bisection)
-DCN_BW = 25e9                # B/s per host, cross-pod
-HBM_BYTES = 16 * 1024**3     # 16 GiB
-VMEM_BYTES = 128 * 1024**2   # ~128 MiB vector memory
+# -- device table ----------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class DeviceSpec:
+    """Per-chip peaks of one accelerator kind (keyed by ``device_kind``)."""
+
+    peak_flops_bf16: float   # FLOP/s
+    peak_ops_int8: float     # OP/s
+    hbm_bw: float            # B/s
+    hbm_bytes: int
+    ici_bw: float            # B/s per ICI link (four links per chip)
+    vmem_bytes: int          # vector memory of one TensorCore
+    source: str
+
+
+# Peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 394 TOP/s
+# int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip). VMEM: the Pallas
+# TPU documentation's per-core vector memory of a v5e TensorCore (128 MiB).
+DEVICES = {
+    "TPU v5 lite": DeviceSpec(
+        peak_flops_bf16=197e12, peak_ops_int8=394e12, hbm_bw=819e9,
+        hbm_bytes=16 * 10**9, ici_bw=1600e9 / 8 / 4, vmem_bytes=128 * 2**20,
+        source="Google Cloud documentation, TPU v5e"),
+}
+
+# The chip the Pallas kernels are tiled for: autotuner budgets and the
+# compiler's scoped-VMEM limit derive from this entry (kernels/dispatch.py,
+# kernels/launch.py), whatever backend happens to trace them.
+TARGET_KIND = "TPU v5 lite"
+
+# Cross-pod data-centre network, per host (collective cost model only).
+DCN_BW = 25e9
+
+
+def device_spec(kind: str | None = None) -> DeviceSpec:
+    """The table entry for ``kind`` (default: the first visible device).
+
+    A device that is not in the table is an error: no metric may be
+    computed against another chip's peaks.
+    """
+    if kind is None:
+        kind = jax.devices()[0].device_kind
+    try:
+        return DEVICES[kind]
+    except KeyError:
+        raise KeyError(
+            f"device kind {kind!r} is not in the peaks table "
+            f"(known: {sorted(DEVICES)})") from None
+
+
+def target_spec() -> DeviceSpec:
+    return DEVICES[TARGET_KIND]

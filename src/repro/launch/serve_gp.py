@@ -151,11 +151,32 @@ def _canonical_key(x) -> str:
     return repr(x)
 
 
+def _slab_callable(make):
+    """The slab step as one callable over its two jitted variants — with
+    and without per-row ξ overrides (``xi_rows`` None) — each built by
+    ``make(with_xi)`` on first use."""
+    variants = {}
+
+    def variant(with_xi: bool):
+        if with_xi not in variants:
+            variants[with_xi] = make(with_xi)
+        return variants[with_xi]
+
+    def fn(*args):
+        return variant(args[-1] is not None)(*args)
+
+    fn.variant = variant
+    return fn
+
+
 def _welford_merge(count, m, m2, batch: np.ndarray):
     """Chan et al. parallel merge of a k-sample batch into (count, m, m2)."""
     k = batch.shape[0]
     bm = batch.mean(axis=0)
-    bm2 = ((batch - bm) ** 2).sum(axis=0)
+    dev = batch - bm  # one batch-sized temporary: fields can be 10^8 points
+    np.square(dev, out=dev)
+    bm2 = dev.sum(axis=0)
+    del dev
     if count == 0:
         return k, bm, bm2
     tot = count + k
@@ -167,6 +188,13 @@ def _welford_merge(count, m, m2, batch: np.ndarray):
 
 def _all_finite(x) -> bool:
     return bool(np.isfinite(np.asarray(x, np.float64)).all())
+
+
+@jax.jit
+def _device_leaves_finite(leaves) -> jax.Array:
+    """Every device leaf finite, checked where the leaves live (no host
+    copy of a 10^8-point posterior) in one program per tree structure."""
+    return jnp.all(jnp.stack([jnp.isfinite(x).all() for x in leaves]))
 
 
 class GPFieldServer:
@@ -282,8 +310,13 @@ class GPFieldServer:
     def serving_mode(self) -> str:
         """Where on the degradation ladder this server executes: sharded
         pallas → single-device pallas → jnp reference (DESIGN.md §15)."""
+        return self._mode_for(self.posterior.icr)
+
+    def _mode_for(self, icr) -> str:
         tier = "single" if self.mesh is None else f"sharded-{self.shard}"
-        return f"{tier}:{dispatch.select_backend()}"
+        backend = (dispatch.select_backend() if icr.use_pallas
+                   else dispatch.require_reference_allowed())
+        return f"{tier}:{backend}"
 
     # -- executable cache ------------------------------------------------------
     def _cache_key(self, post: Posterior):
@@ -315,10 +348,12 @@ class GPFieldServer:
         for name, leaves in (("theta", list((post.theta or {}).values())),
                              ("mean", list(post.mean)),
                              ("std", list(post.std()))):
-            for leaf in leaves:
-                if not _all_finite(leaf):
-                    raise ValueError(
-                        f"posterior rejected: non-finite values in {name}")
+            dev = [x for x in leaves if isinstance(x, jax.Array)]
+            host = [x for x in leaves if not isinstance(x, jax.Array)]
+            if (dev and not bool(_device_leaves_finite(dev))) \
+                    or not all(_all_finite(x) for x in host):
+                raise ValueError(
+                    f"posterior rejected: non-finite values in {name}")
 
     def set_posterior(self, post: Posterior, *, rewarm: bool = False):
         """Point the server at a (new) fit. Same (chart geometry, θ, dtype
@@ -371,12 +406,15 @@ class GPFieldServer:
 
         def draw(mean, std, seed, row, use_xi, xi_row):
             """One row's excitation: (seed, row)-keyed noise around the
-            posterior mean — or the request's own ξ when it supplied one."""
+            posterior mean — or the request's own ξ when it supplied one
+            (``xi_row`` is None when no row of the slab did)."""
             k = jax.random.fold_in(jax.random.PRNGKey(seed), row)
             ks = jax.random.split(k, len(shapes))
+            xs = [None] * len(shapes) if xi_row is None else xi_row
             out = []
-            for kk, m, s, x in zip(ks, mean, std, xi_row):
-                base = jnp.where(use_xi, x.astype(m.dtype), m)
+            for kk, m, s, x in zip(ks, mean, std, xs):
+                base = m if x is None else jnp.where(use_xi,
+                                                     x.astype(m.dtype), m)
                 out.append(base + s * jax.random.normal(kk, m.shape, m.dtype))
             return out
 
@@ -392,9 +430,10 @@ class GPFieldServer:
                 return icr.apply_sqrt_batch(mats, xi).astype(jnp.float32)
 
             mats = icr.matrices_cached(post.theta)
-            entry = {"mats": mats, "fn": jax.jit(slab_fn)}
+            entry = {"mats": mats,
+                     "fn": _slab_callable(lambda with_xi: jax.jit(slab_fn))}
         entry.update(plan=plan, capacity=capacity, shapes=shapes,
-                     mode=self.serving_mode)
+                     mode=self._mode_for(icr))
         return entry
 
     def _build_sample_sharded(self, post: Posterior, draw) -> dict:
@@ -420,11 +459,14 @@ class GPFieldServer:
         mats, report = elastic.remesh_report(mats, mesh, repl(mats))
         self.degradations.extend(report)
         n_levels = len(post.mean)
-        in_specs = (repl(mats), [P()] * n_levels, [P()] * n_levels,
-                    P(axes), P(axes), P(axes), [P(axes)] * n_levels)
-        fn = jax.jit(shard_map(slab_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=P(axes), check_vma=False))
-        return {"mats": mats, "fn": fn}
+        def make(with_xi):
+            in_specs = (repl(mats), [P()] * n_levels, [P()] * n_levels,
+                        P(axes), P(axes), P(axes),
+                        [P(axes)] * n_levels if with_xi else None)
+            return jax.jit(shard_map(slab_fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=P(axes), check_vma=False))
+
+        return {"mats": mats, "fn": _slab_callable(make)}
 
     def _build_chart_sharded(self, post: Posterior, draw, shapes) -> dict:
         """Spatial decomposition via the DistributedICR halo-exchange body:
@@ -463,20 +505,24 @@ class GPFieldServer:
             out = jax.vmap(one)(seeds, rows, use_xi, xi_rows)
             return out.astype(jnp.float32)
 
-        # the sharded body runs the joint path; place the matrices with the
-        # distributed specs and surface any degradation (e.g. a ring the
-        # family counts don't divide would replicate — reported, not hidden)
-        mats = icr.matrices_cached(post.theta, joint=True, axes=False)
+        # the matrices the sharded body refines with (per-axis factors on
+        # the kernel path of an N-D chart, else joint), placed with the
+        # distributed specs; any degradation (e.g. a ring the family counts
+        # don't divide would replicate) is reported, not hidden
+        axes = dist.uses_axis_factors()
+        mats = icr.matrices_cached(post.theta, joint=not axes, axes=axes)
         mat_specs = dist.mat_specs()
         mats, report = elastic.remesh_report(mats, mesh, mat_specs)
         self.degradations.extend(report)
         n_levels = len(post.mean)
-        in_specs = (mat_specs, [P()] * n_levels, [P()] * n_levels,
-                    P(), P(), P(), [P()] * n_levels)
-        fn = jax.jit(shard_map(slab_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=P(None, *dist.out_spec()),
-                               check_vma=False))
-        return {"mats": mats, "fn": fn}
+        def make(with_xi):
+            in_specs = (mat_specs, [P()] * n_levels, [P()] * n_levels,
+                        P(), P(), P(), [P()] * n_levels if with_xi else None)
+            return jax.jit(shard_map(slab_fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=P(None, *dist.out_spec()),
+                                     check_vma=False))
+
+        return {"mats": mats, "fn": _slab_callable(make)}
 
     # -- admission -------------------------------------------------------------
     def _reject(self, req: GPRequest, code: str, message: str):
@@ -590,8 +636,12 @@ class GPFieldServer:
         seeds = np.zeros(cap, np.int32)
         idxs = np.full(cap, _PAD_ROW, np.int32)
         flags = np.zeros(cap, bool)
-        xi_rows = [np.zeros((cap,) + tuple(s), np.float32)
-                   for s in entry["shapes"]]
+        # a slab in which no request supplies ξ ships none: at deployment
+        # sizes the ξ override is as large as the field itself
+        xi_rows = None
+        if any(req.xi is not None for req, _ in rows):
+            xi_rows = [np.zeros((cap,) + tuple(s), np.float32)
+                       for s in entry["shapes"]]
         for i, (req, ridx) in enumerate(rows):
             seeds[i], idxs[i] = req.seed, ridx
             if req.xi is not None:
@@ -600,7 +650,8 @@ class GPFieldServer:
                     xi_rows[lvl][i] = np.asarray(leaf, np.float32)
         return (entry["mats"], entry["mean"], entry["std"],
                 jnp.asarray(seeds), jnp.asarray(idxs), jnp.asarray(flags),
-                [jnp.asarray(x) for x in xi_rows])
+                None if xi_rows is None
+                else [jnp.asarray(x) for x in xi_rows])
 
     def _execute_once(self, entry: dict, args: tuple) -> np.ndarray:
         """One slab attempt under the fault supervisor: transient errors
@@ -781,9 +832,8 @@ class GPFieldServer:
             return xi, eps
 
         xi, eps = jax.vmap(draw)(jnp.arange(n))
-        fields = np.asarray(
-            icr.apply_sqrt_batch(state["system"].mats, xi)
-        ).astype(np.float32).reshape(n, -1)
+        fields = np.asarray(state["system"].sqrt_batch(xi)
+                            ).astype(np.float32).reshape(n, -1)
         y = jnp.asarray(np.asarray(req.y, np.float32).ravel())[None, :]
         b = jnp.concatenate(
             [y, y - op.apply(jnp.asarray(fields)) - noise_std * eps],
@@ -990,7 +1040,7 @@ class GPFieldServer:
         subsystem (repro.analysis) so a serving-path route or dtype
         regression is caught by the golden diff, not by wall-time noise."""
         e = self._entry
-        return e["fn"].lower(*self._slab_args(e, []))
+        return e["fn"].variant(False).lower(*self._slab_args(e, []))
 
 
 # -- demo / smoke entry point ---------------------------------------------------
@@ -1048,6 +1098,9 @@ def main():
     ap.add_argument("--mesh", type=int, default=0,
                     help="shard over the first N local devices (0: off)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

@@ -15,7 +15,7 @@ SPMD module are local, so the sum is per-device traffic. all-reduce operands
 are counted twice (reduce-scatter + all-gather phases of a ring).
 
 The dominant term approximates the step's lower-bound time on the target
-(TPU v5e constants in launch/mesh.py); the ratio MODEL_FLOPS/HLO_FLOPs
+(the target chip's entry of the launch/mesh.py device table); the ratio MODEL_FLOPS/HLO_FLOPs
 separates "useful" model math from remat/dispatch overhead.
 """
 from __future__ import annotations
@@ -125,15 +125,15 @@ class RooflineTerms:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / hw.PEAK_FLOPS_BF16
+        return self.flops / hw.target_spec().peak_flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_accessed / hw.HBM_BW
+        return self.bytes_accessed / hw.target_spec().hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / hw.ICI_BW
+        return self.coll_bytes / hw.target_spec().ici_bw
 
     @property
     def dominant(self) -> str:
@@ -151,7 +151,7 @@ class RooflineTerms:
         (MODEL_FLOPS at peak): the dry-run analogue of MFU."""
         if self.bound_time == 0:
             return 0.0
-        return (self.model_flops / hw.PEAK_FLOPS_BF16) / self.bound_time
+        return (self.model_flops / hw.target_spec().peak_flops_bf16) / self.bound_time
 
     @property
     def useful_flops_ratio(self) -> float:
@@ -215,7 +215,7 @@ def analyze_compiled(compiled, model_flops_per_device: float = 0.0
             # both sides would double-book them
             mem["total_bytes"] = (mem["argument_bytes"] + mem["output_bytes"]
                                   + mem["temp_bytes"] - mem["alias_bytes"])
-            mem["fits_hbm"] = mem["total_bytes"] <= hw.HBM_BYTES
+            mem["fits_hbm"] = mem["total_bytes"] <= hw.target_spec().hbm_bytes
     except Exception:
         pass
     terms = RooflineTerms(

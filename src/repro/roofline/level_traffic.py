@@ -8,9 +8,8 @@ kernels actually move data:
 
   ``stationary-1d`` / ``charted-1d`` / ``nd-fused``
       read L (+ boundary/tile padding) + read ξ + write N + matrices —
-      one launch, the minimal traffic (DESIGN.md §2/§10). The fused N-D
-      halo re-read (q_max/b_f of the coarse tile) is below the model's
-      resolution and ignored.
+      one launch (DESIGN.md §2/§10). The fused N-D halo re-read (q_max/b_f
+      of the coarse tile) is below the model's resolution and ignored.
 
   ``pyramid``
       the VMEM-resident multi-level launch (DESIGN.md §11): a covered
@@ -26,6 +25,12 @@ kernels actually move data:
       and every pass whose axis is not already minor pays a relayout —
       XLA materializes a contiguous transpose around the kernel call, a
       read+write of the field on each side.
+
+Every kernel route also pays the phase-plane relayouts (``kernels/
+planes.py``, DESIGN.md §3): XLA passes outside the launch that split the
+coarse field and ξ into planes and interleave the output planes back, each
+a read+write of its array, plus the charted matrices' coefficient planes.
+They are the ``"relayout"`` term of every kernel route.
 
   ``reference``
       the joint jnp einsum path: the (T, n_csz^d) window tensor is
@@ -69,12 +74,14 @@ def _padded_extent(geom, a: int) -> int:
     return max(n, (geom.T[a] + q_max) * s)
 
 
-def _axis_mat_bytes(geom, itemsize: int) -> int:
-    """Per-axis Kronecker factors (R_a, sqrtD_a)."""
+def _axis_mat_bytes(geom, itemsize: int, *, fused: bool = False) -> int:
+    """Per-axis Kronecker factors (R_a, sqrtD_a). A single-launch route
+    (``fused``) reads sqrtD of axis 0 only: the other noise factors are
+    folded into ξ before the launch."""
     f, c = geom.n_fsz, geom.n_csz
-    per = f * c + f * f
     return itemsize * sum(
-        (geom.T[a] if geom.kept_T[a] > 1 else 1) * per
+        (geom.T[a] if geom.kept_T[a] > 1 else 1)
+        * (f * c + (f * f if a == 0 or not fused else 0))
         for a in range(len(geom.coarse_shape))
     )
 
@@ -115,24 +122,21 @@ def refine_level_traffic(geom, route: str, *, itemsize: int | None = None,
     n_out = _prod(geom.fine_shape)
     xi_elems = _prod(geom.T) * fsz**nd
 
-    if route == "pyramid":
+    if route in ("pyramid", "stationary-1d", "charted-1d", "nd-fused"):
+        pyr = route == "pyramid"
         field_read = (_prod(_padded_extent(geom, a) for a in range(nd))
-                      if first else 0)
+                      if first or not pyr else 0)
+        fine_write = n_out if last or not pyr else 0
+        mats = _axis_mat_bytes(geom, itemsize, fused=True)
         out = {
             "field_read": samples * itemsize * field_read,
             "xi_read": samples * itemsize * xi_elems,
-            "fine_write": samples * itemsize * (n_out if last else 0),
-            "matrices": _axis_mat_bytes(geom, itemsize),
-            "relayout": 0,
-        }
-    elif route in ("stationary-1d", "charted-1d", "nd-fused"):
-        field_read = _prod(_padded_extent(geom, a) for a in range(nd))
-        out = {
-            "field_read": samples * itemsize * field_read,
-            "xi_read": samples * itemsize * xi_elems,
-            "fine_write": samples * itemsize * n_out,
-            "matrices": _axis_mat_bytes(geom, itemsize),
-            "relayout": 0,
+            "fine_write": samples * itemsize * fine_write,
+            "matrices": mats,
+            # phase-plane split of the coarse field and ξ, interleave of
+            # the output, coefficient planes: read+write each
+            "relayout": 2 * (samples * itemsize
+                             * (field_read + xi_elems + fine_write) + mats),
         }
     elif route == "nd-axes":
         extents = list(geom.coarse_shape)
@@ -147,8 +151,11 @@ def refine_level_traffic(geom, route: str, *, itemsize: int | None = None,
             out_extents[a] = geom.T[a] * fsz
             n_pass_out = _prod(out_extents)
             kernel_bytes += _prod(in_pad) + n_pass_out
+            # each pass's phase-plane split and interleave (read+write)
+            relayout += 2 * (_prod(in_pad) + n_pass_out)
             if a == 0:
                 kernel_bytes += xi_elems  # the only ξ read (noise=False mode)
+                relayout += 2 * xi_elems
             if a != nd - 1:
                 # moveaxis relayout around the launch: read+write the field
                 # on the way in and on the way out
@@ -159,7 +166,8 @@ def refine_level_traffic(geom, route: str, *, itemsize: int | None = None,
             "xi_read": 0,  # folded into field_read per pass above
             "fine_write": 0,
             "matrices": _axis_mat_bytes(geom, itemsize),
-            "relayout": samples * itemsize * relayout,
+            "relayout": (samples * itemsize * relayout
+                         + 2 * _axis_mat_bytes(geom, itemsize)),
         }
     elif route == "reference":
         n_in = _prod(_padded_extent(geom, a) for a in range(nd))

@@ -144,6 +144,10 @@ class ConditionSystem:
     matvec: Callable[[Array], Array]     # (k, O) -> (k, O)
     precond: Optional[Callable]          # ICR-whitened M⁻¹, or None
     mesh: object = None
+    # jitted (mats, ·) programs: one compile per apply, where an eager
+    # apply compiles every op of every level on its own (minutes on a TPU)
+    _sqrt_batch: Optional[Callable] = None   # (mats, ξ batch) -> fields
+    _correct: Optional[Callable] = None      # (mats, α) -> K Wᵀ α
 
     @property
     def n_obs(self) -> int:
@@ -160,20 +164,26 @@ class ConditionSystem:
                              self.noise_var, eye)
         return jnp.linalg.solve(a, jnp.asarray(b).T).T
 
+    def sqrt_batch(self, xi: list) -> Array:
+        """S ξ for a batch of excitations: (k, *final_shape) fields."""
+        return self._sqrt_batch(self.mats, xi)
+
     def correct(self, alpha: Array) -> Array:
         """K Wᵀ α for a batch of solutions: (k, O) -> (k, *final_shape)
         posterior corrections (one Sᵀ + one S application)."""
-        xi = self.project_xi(alpha)
-        return self.icr.apply_sqrt_batch(self.mats, xi)
+        return self._correct(self.mats, jnp.asarray(alpha))
 
     def project_xi(self, alpha: Array) -> list:
         """Sᵀ Wᵀ α: the whitened (ξ-space) representation of the
         conditioning correction — a delta ``Posterior.mean`` serves the
         CG posterior mean through the existing sampling slab unchanged."""
-        shape = self.icr.chart.final_shape
-        u = self.obs.apply_t(jnp.asarray(alpha))
-        u = u.reshape((u.shape[0],) + tuple(shape))
-        return _sqrt_t_batch(self.icr, self.mats, u)
+        return _project_xi(self.icr, self.obs, self.mats, jnp.asarray(alpha))
+
+
+def _project_xi(icr, obs, mats, alpha: Array) -> list:
+    u = obs.apply_t(alpha)
+    u = u.reshape((u.shape[0],) + tuple(icr.chart.final_shape))
+    return _sqrt_t_batch(icr, mats, u)
 
 
 def _sqrt_t_batch(icr, mats, u: Array) -> list:
@@ -284,6 +294,9 @@ def build_condition_system(icr, obs, noise_var: float, *, theta=None,
     precond = (icr_whitening_precond(icr, mats, obs, noise_var,
                                      max_basis=precond_max_basis)
                if use_precond else None)
-    return ConditionSystem(icr=icr, obs=obs, noise_var=noise_var,
-                           mats=mats, matvec=matvec, precond=precond,
-                           mesh=mesh)
+    return ConditionSystem(
+        icr=icr, obs=obs, noise_var=noise_var, mats=mats, matvec=matvec,
+        precond=precond, mesh=mesh,
+        _sqrt_batch=jax.jit(lambda m, xi: icr.apply_sqrt_batch(m, xi)),
+        _correct=jax.jit(lambda m, a: icr.apply_sqrt_batch(
+            m, _project_xi(icr, obs, m, a))))

@@ -22,6 +22,7 @@ from repro.analysis import (
 )
 from repro.analysis.diff import ADDED, CHANGED, REMOVED
 from repro.core.charts import regular_chart
+from repro.core.refine import LevelGeom
 from repro.kernels import dispatch
 
 
@@ -102,10 +103,17 @@ def test_vmem_lint_flags_oversized_tile():
     entries = dispatch.plan_signature(chart, platform="tpu", samples=4,
                                       pyramid=False)
     doctored = copy.deepcopy(entries)
-    victim = next(e for e in doctored
-                  if e["route"] != dispatch.ROUTE_REFERENCE)
+    victim = doctored[-1]  # the widest level: 1024 families
     victim["block_families"]["0"] = 1 << 24  # absurd: ~16M families/tile
-    findings = lint_vmem(chart, samples=4, entries=doctored, label="t")
+    # a request past the level is one block over the whole level; under a
+    # budget that block does not fit, the lint must flag it
+    t = LevelGeom.for_level(chart, victim["level"]).T[0]
+    small = dispatch.block1d_bytes(t, chart.n_csz, chart.n_fsz,
+                                   charted=False, block_families=t,
+                                   batch_block=victim["sample_block"] or 1
+                                   ) - 1
+    findings = lint_vmem(chart, samples=4, entries=doctored, label="t",
+                         vmem_budget=small)
     assert any("exceeds VMEM budget" in f.message for f in findings)
     # and the untouched plan is clean
     assert lint_vmem(chart, samples=4, entries=entries, label="t") == []

@@ -34,6 +34,30 @@ def test_select_backend_default_and_override(monkeypatch):
         dispatch.select_backend()
 
 
+def test_tpu_runs_pallas_or_fails(monkeypatch):
+    """On a TPU the compiled kernels are the only backend: an override
+    asking for another one is an error, not a silent fallback; a level
+    without a kernel route fails on the pallas backend; any other
+    platform has no backend at all."""
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    assert dispatch.select_backend() == dispatch.BACKEND_PALLAS
+    monkeypatch.setenv("REPRO_BACKEND", "pallas")
+    assert dispatch.select_backend() == dispatch.BACKEND_PALLAS
+    for other in ("interpret", "reference"):
+        monkeypatch.setenv("REPRO_BACKEND", other)
+        with pytest.raises(RuntimeError, match="TPU"):
+            dispatch.select_backend()
+    with pytest.raises(RuntimeError, match="no kernel backend"):
+        dispatch.select_backend(platform="gpu")
+    c = regular_chart((8, 8), 1)
+    geom = LevelGeom.for_level(c, 0)
+    assert dispatch.route_for(geom) == dispatch.ROUTE_REFERENCE
+    with pytest.raises(ValueError, match="no kernel route"):
+        dispatch.refine(jnp.zeros(geom.coarse_shape), None, None, None,
+                        geom, backend=dispatch.BACKEND_PALLAS)
+
+
 def test_refine_oracle_branch_matches_interpret():
     """dispatch.refine on a factored N-D level with backend=reference (no
     joint matrices!) must equal the interpret megakernel at 1e-5 — the

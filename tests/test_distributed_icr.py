@@ -131,3 +131,45 @@ def test_xi_specs_structure():
     assert len(specs) == len(shapes) == icr.chart.n_levels + 1
     assert shapes[0] == (64,)
     assert shapes[1] == (64, 2)  # reflect: every stride-1 pixel anchors a family
+
+
+def _dot_precisions(jaxpr, shape):
+    """Precision params of every dot_general (recursing into sub-jaxprs)
+    that contracts an operand of ``shape``."""
+    from jax.extend import core as jcore
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and any(
+                tuple(v.aval.shape) == shape for v in eqn.invars):
+            found.append(eqn.params["precision"])
+        for p in eqn.params.values():
+            subs = p if isinstance(p, (list, tuple)) else [p]
+            for sub in subs:
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    found += _dot_precisions(sub.jaxpr, shape)
+                elif isinstance(sub, jcore.Jaxpr):
+                    found += _dot_precisions(sub, shape)
+    return found
+
+
+@pytest.mark.parametrize("pallas", [False, True])
+def test_chart_sharded_level0_at_highest_precision(key, pallas):
+    """The chart-sharded program starts its field with the same
+    full-precision level-0 matmul as one device (at the TPU's default
+    precision it would be rounded to bf16)."""
+    import jax
+    from jax.lax import Precision
+
+    icr = ICR(chart=regular_chart(32, 3, boundary="reflect"),
+              kernel=matern32.with_defaults(rho=10.0), use_pallas=pallas)
+    mesh = make_mesh((1,), ("space",))
+    dist = DistributedICR(icr=icr, mesh=mesh, axis_names=("space",))
+    with use_mesh(mesh):
+        mats, xi = dist.matrices(), dist.init_xi(key)
+        closed = jax.make_jaxpr(dist.apply_sqrt)(mats, xi)
+    precs = _dot_precisions(closed.jaxpr, tuple(mats["sqrt0"].shape))
+    assert precs, "no level-0 dot_general in the chart-sharded program"
+    for prec in precs:
+        assert prec is not None and all(
+            p == Precision.HIGHEST for p in prec), precs

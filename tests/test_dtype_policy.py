@@ -177,22 +177,38 @@ class TestDtypeBytes:
 
     def test_autotune_is_itemsize_aware(self):
         """Half the bytes per element -> at least as many families per
-        VMEM tile, strictly more when the fp32 block was budget-bound."""
+        VMEM tile, strictly more when the fp32 block was budget-bound.
+        The model halves the storage-dtype blocks; the kernel body's
+        float32 values (the v5e VPU has no bf16 arithmetic) do not."""
+        kw = dict(charted=True, block_families=1024)
+        m32 = dispatch.block1d_bytes(10**6, 5, 4, itemsize=4, **kw)
+        m16 = dispatch.block1d_bytes(10**6, 5, 4, itemsize=2, **kw)
+        assert m16 < m32
+        budget = (m16 + m32) // 2  # fp32 1024-block busts it, bf16 fits
         b32 = dispatch.autotune_block_families(10**6, 5, 4, charted=True,
-                                               itemsize=4)
+                                               itemsize=4,
+                                               vmem_budget=budget)
         b16 = dispatch.autotune_block_families(10**6, 5, 4, charted=True,
-                                               itemsize=2)
+                                               itemsize=2,
+                                               vmem_budget=budget)
         assert b16 >= 2 * b32
 
     def test_pyramid_cover_grows_at_bf16(self):
         """A chart whose fp32 working set busts the budget can still be
         fully covered at bf16 (the §11 residency criterion is dtype-aware).
-        """
-        deep = galactic_dust_chart((8, 16, 16), n_levels=4)
-        geoms = [LevelGeom.for_level(deep, l) for l in range(4)]
-        budget = 160 * 2**20  # between the fp32 (~268 MiB) and bf16 totals
-        k32, _ = dispatch.autotune_pyramid(geoms, itemsize=4,
-                                           vmem_budget=budget)
-        k16, _ = dispatch.autotune_pyramid(geoms, itemsize=2,
-                                           vmem_budget=budget)
-        assert k16 > k32
+        A 16-sample slab fills the bf16 sublane tile, so the bf16 blocks
+        are half the fp32 ones (the f32 body values are not)."""
+        deep = regular_chart(1024, 8, boundary="reflect")
+        geoms = [LevelGeom.for_level(deep, l) for l in range(8)]
+        budget = 128 * 2**20  # between the bf16 (~104 MiB) and fp32
+        # (~152 MiB) working sets of all eight levels at 16 samples
+        w32 = dispatch.pyramid_bytes(geoms, s_b=16, itemsize=4)
+        w16 = dispatch.pyramid_bytes(geoms, s_b=16, itemsize=2)
+        assert w16 <= budget < w32
+        # so the autotuner covers all levels with the whole slab at bf16,
+        # and has to halve the slab at fp32
+        k32, s32 = dispatch.autotune_pyramid(geoms, samples=16, itemsize=4,
+                                             vmem_budget=budget)
+        k16, s16 = dispatch.autotune_pyramid(geoms, samples=16, itemsize=2,
+                                             vmem_budget=budget)
+        assert (k16, s16) == (8, 16) and k32 == 8 and s32 < s16

@@ -77,3 +77,28 @@ def test_remesh_roundtrip():
     # non-divisible: b (3,) against data axis — replicated, value intact
     out_b = remesh({"b": placed["b"]}, mesh_b, {"b": P("data")})
     np.testing.assert_array_equal(np.asarray(out_b["b"]), np.ones((3,)))
+
+
+@pytest.mark.parametrize("exc", [
+    RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: ..."),
+    RuntimeError("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error"),
+    ValueError("lowering: bad block shape"),
+    NotImplementedError("Unimplemented primitive in Pallas TPU lowering"),
+], ids=["mosaic", "hbm", "value", "not-implemented"])
+def test_serving_supervisor_never_retries_compile_errors(exc):
+    """A compile or lowering failure is deterministic: the slab attempt
+    raises at once; only transient runtime errors are retried."""
+    from repro.distributed.fault import ServingFaultSupervisor, retryable
+
+    assert not retryable(exc)
+    assert retryable(RuntimeError("chip fell over"))
+    sup = ServingFaultSupervisor()
+    calls = {"n": 0}
+
+    def attempt():
+        calls["n"] += 1
+        raise exc
+
+    with pytest.raises(type(exc)):
+        sup.execute(attempt)
+    assert calls["n"] == 1 and sup.transient_retries == 0

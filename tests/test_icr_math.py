@@ -195,3 +195,62 @@ class TestSampling:
 
         g = jax.grad(loss)(jnp.asarray(0.5))
         assert np.isfinite(float(g)) and abs(float(g)) > 0
+
+
+def test_charted_matrices_chunked_over_families(monkeypatch):
+    """A 1-D charted level past ``FAMILY_CHUNK`` families builds its
+    matrices chunk by chunk (bounding the padded batched linear algebra on
+    the chip), identical to one batch, uneven last chunk included."""
+    from repro.core import refine
+
+    c = log_chart(32, 3, n_csz=5, n_fsz=4, delta0=0.05)
+    k = matern32.with_defaults(rho=1.0)()
+    lvl = 2
+    whole = refinement_matrices_level(c, k, lvl)
+    assert whole[0].shape[0] > 24 and whole[0].shape[0] % 24  # 3 chunks
+    monkeypatch.setattr(refine, "FAMILY_CHUNK", 24)
+    chunked = refinement_matrices_level(c, k, lvl)
+    for a, b in zip(whole, chunked):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-6,
+                                   atol=1e-7)
+
+
+@pytest.mark.parametrize("boundary", ["reflect", "shrink"])
+def test_family_subset_windows_match_full(boundary):
+    """Windows built for a subset of families (the one representative
+    family of an invariant axis) are those rows of the full windows, at
+    both ends of the axis included."""
+    c = regular_chart((20, 12), 3, n_csz=5, n_fsz=4, boundary=boundary)
+    for lvl in range(c.n_levels):
+        for a in range(c.ndim):
+            t = c.family_count(lvl, a)
+            fams = sorted({0, min(t - 1, c.b), t - 1})
+            np.testing.assert_array_equal(
+                c.axis_coarse_windows(lvl, a, fams),
+                c.axis_coarse_windows(lvl, a)[fams])
+            np.testing.assert_array_equal(
+                c.axis_fine_windows(lvl, a, fams),
+                c.axis_fine_windows(lvl, a)[fams])
+
+
+def test_level0_sqrt_factored_on_host_off_cpu(monkeypatch):
+    """Off the CPU a concrete level-0 matrix is factored on the host's CPU
+    device and the factor comes back to the matrix's device: the same
+    factor as in place, and θ-gradients (traced) still flow."""
+    from repro.core import refine
+
+    c = regular_chart(24, 1)
+    k = matern32.with_defaults(rho=3.0)()
+    here = refine.level0_sqrt(c, k)
+    monkeypatch.setattr(refine.jax, "default_backend", lambda: "tpu")
+    host = refine.level0_sqrt(c, k)
+    assert host.devices() == here.devices()
+    np.testing.assert_allclose(np.asarray(host), np.asarray(here),
+                               rtol=1e-6, atol=1e-7)
+
+    def loss(rho):
+        kk = matern32.with_defaults(rho=rho)()
+        return jnp.sum(refine.level0_sqrt(c, kk) ** 2)  # trace(K): smooth
+
+    assert np.isfinite(float(jax.grad(loss)(3.0)))

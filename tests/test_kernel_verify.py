@@ -30,11 +30,12 @@ def scenario(label):
 
 @pytest.fixture(scope="module")
 def tod_plans():
-    """Forward + adjoint 1-D plans of the tod quick chart's last level."""
+    """Forward + adjoint 1-D plans of the tod quick chart's last level,
+    tiled at one 128-family lane tile so they carry halo views."""
     scn = scenario("tod-fp32")
     geom = LevelGeom.for_level(scn.chart(), 2)
     fwd, adj = dsp.level_launch_plans(geom, samples=scn.samples,
-                                     dtype="float32")
+                                     dtype="float32", block_families=128)
     return geom, fwd, adj
 
 

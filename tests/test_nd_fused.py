@@ -219,9 +219,11 @@ class TestTrafficModel:
             assert hb["selected"] == hb[e["route"]]
 
     def test_model_matches_first_principles(self):
-        """The fused estimate is read L + read ξ + write N + matrices —
-        recomputed here from the chart shapes alone (guards the plan wiring
-        against drifting from the roofline model)."""
+        """The fused estimate is read L + read ξ + write N + matrices for
+        the launch, and a read+write of each of those arrays for the
+        phase-plane relayouts around it — recomputed here from the chart
+        shapes alone (guards the plan wiring against drifting from the
+        roofline model)."""
         c = galactic_dust_chart((6, 8, 8), n_levels=2)
         for lvl in range(c.n_levels):
             geom = LevelGeom.for_level(c, lvl)
@@ -232,7 +234,8 @@ class TestTrafficModel:
             for a, n in enumerate(geom.coarse_shape):
                 read_l *= max(n + 2 * geom.b, (geom.T[a] + q) * s)
             n_out = int(np.prod(geom.fine_shape))
-            approx = 4 * (read_l + 2 * n_out)  # field + ξ + fine, f32
+            # field + ξ + fine, f32: the launch, then the relayouts
+            approx = 3 * 4 * (read_l + 2 * n_out)
             assert abs(got - approx) / approx < 0.10, (got, approx)
 
     def test_samples_amortize_matrices(self):

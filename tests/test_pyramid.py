@@ -34,6 +34,15 @@ CHARTS = [
     ("2d-shrink", lambda: regular_chart((12, 10), 2, boundary="shrink"), 4.0),
     ("2d-reflect", lambda: regular_chart((12, 16), 2, boundary="reflect"),
      4.0),
+    # 3-D tiles merge their two innermost position dims: the charted
+    # radial axis as the inner, then the outer, of the merged pair, and
+    # the zero-filled shrink boundary across the merged rows
+    ("3d-charted-inner",
+     lambda: galactic_dust_chart((12, 6, 6), n_levels=2), 0.5),
+    ("3d-charted-outer",
+     lambda: galactic_dust_chart((8, 6, 12), n_levels=2), 0.5),
+    ("3d-shrink", lambda: regular_chart((12, 10, 10), 2, boundary="shrink"),
+     4.0),
     ("3d-dust-reflect", lambda: galactic_dust_chart((6, 8, 8), n_levels=2),
      0.5),
 ]
@@ -202,7 +211,7 @@ class TestAutotunePyramid:
             cover = dispatch.autotune_pyramid(geoms, vmem_budget=budget)
             ks.append(0 if cover is None else cover[0])
         assert ks == sorted(ks) and ks[-1] == 4
-        assert ks[2] == 3  # the default budget splits exactly at level 3
+        assert ks[2] == 2  # the default budget splits exactly at level 2
 
     def test_one_level_is_not_a_pyramid(self):
         geoms = [LevelGeom.for_level(galactic_dust_chart((6, 8, 8), 2), 0)]
@@ -213,10 +222,13 @@ class TestAutotunePyramid:
         geoms = [LevelGeom.for_level(c, l) for l in range(2)]
         k, s_b = dispatch.autotune_pyramid(geoms, samples=16)
         assert k == 2 and 1 <= s_b <= 16
-        total = sum(
-            dispatch._fused_tile_bytes(g, dispatch._pyramid_charted(g),
-                                       g.T[0], s_b, 4) for g in geoms)
-        assert total <= dispatch.VMEM_BUDGET_BYTES
+        # the slab is the largest the pyramid's own working-set model
+        # holds: it fits, and doubling it (short of all 16) would not
+        budget = dispatch.VMEM_BUDGET_BYTES
+        assert dispatch.pyramid_bytes(geoms, s_b=s_b, itemsize=4) <= budget
+        if s_b < 16:
+            assert dispatch.pyramid_bytes(geoms, s_b=min(2 * s_b, 16),
+                                          itemsize=4) > budget
 
     def test_reference_level_ends_the_prefix(self):
         """N-D chart without axis factors: nothing is structured, no

@@ -218,6 +218,6 @@ def test_warm_identical_batch_at_least_5x_faster():
     assert cold >= 5.0 * warm, (cold, warm)
     assert srv.cache_hits > hits_before
     assert post.icr.matrices_cache_stats["misses"] == mats_misses
-    fn = srv._entry["fn"]
+    fn = srv._entry["fn"].variant(False)  # these requests supply no ξ
     if hasattr(fn, "_cache_size"):  # retrace detector (jax >= 0.4)
         assert fn._cache_size() == 1

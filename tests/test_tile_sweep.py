@@ -32,13 +32,15 @@ def scenario(label):
 
 
 def draw_1d_configs(rng, t, floor, n):
-    """Valid (block_families, batch_block) pairs, non-powers included."""
-    cfgs = set()
-    while len(cfgs) < n:
-        b_f = int(rng.integers(floor, t + 1))
-        b_b = int(rng.integers(1, SAMPLES + 1))
-        cfgs.add((b_f, b_b))
-    return sorted(cfgs)
+    """Valid (block_families, batch_block) pairs, non-powers included.
+
+    Families ride the lanes: a block is the whole level or a multiple of
+    128 families at or above the floor. The batch rides the sublanes, so
+    a slab of SAMPLES (fewer than one sublane tile) is one block."""
+    choices = [t] + [b for b in range(128, t, 128) if b >= floor]
+    picks = rng.choice(len(choices), size=min(n, len(choices)),
+                       replace=False)
+    return sorted((choices[int(i)], SAMPLES) for i in picks)
 
 
 def assert_plans_clean(plans, geom, route, *, label):
@@ -125,14 +127,20 @@ class TestSweepPyramid:
         scn = scenario("dust-fp32")
         chart = scn.chart()
         kernel = matern32.with_defaults(rho=scn.rho)()
+        # the padded working set of a 3-D polyphase tile grows with the
+        # slab: size the sweep's VMEM budget so every drawn slab is valid
+        budget = dsp.pyramid_bytes(
+            [LevelGeom.for_level(chart, lvl) for lvl in range(2)],
+            s_b=s_b, itemsize=4)
         groups = dsp.chart_launch_plans(chart, samples=SAMPLES,
-                                        dtype="float32", sample_block=s_b)
+                                        dtype="float32", sample_block=s_b,
+                                        vmem_budget=budget)
         grp = groups[0]
         assert grp["route"] == dsp.ROUTE_PYRAMID
         plan = grp["plans"][0]
         assert plan.params["s_b"] == s_b
         geoms = grp["geom"]
-        findings = kv.verify_plan(plan, samples=SAMPLES,
+        findings = kv.verify_plan(plan, samples=SAMPLES, vmem_budget=budget,
                                   scenario=f"dust s_b={s_b}")
         assert findings == [], "\n".join(str(f) for f in findings)
 
