@@ -47,9 +47,10 @@ def level0_field(sqrt0: Array, xi0: Array) -> Array:
     Every path that starts a field (single device, the chart-sharded ring)
     goes through here."""
     hi = jax.lax.Precision.HIGHEST
-    if xi0.ndim == 1:
-        return jnp.matmul(sqrt0, xi0, precision=hi)
-    return jnp.matmul(xi0, sqrt0.T, precision=hi)
+    with jax.named_scope("icr.level0"):
+        if xi0.ndim == 1:
+            return jnp.matmul(sqrt0, xi0, precision=hi)
+        return jnp.matmul(xi0, sqrt0.T, precision=hi)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,6 +236,12 @@ class ICR:
         then each remaining level goes through ``dispatch.refine`` (buffer
         donation deliberately does not apply to the expansive ping-pong
         chain — see the note in kernels/dispatch.py).
+
+        The ops that make level ``l`` (``l = 1..n_levels``, refined with
+        ``xi[l]``) are built under ``jax.named_scope("icr.level<l>")``, the
+        pyramid's under ``"icr.pyramid"`` and level 0's under
+        ``"icr.level0"``: the compiled ops' ``op_name`` metadata, and so a
+        profile, names the level they serve.
         """
         start = 0
         from repro.kernels import dispatch, pyramid
@@ -245,8 +252,9 @@ class ICR:
                 geom = LevelGeom.for_level(self.chart, lvl)
                 ref = lambda f, x: refine_level(
                     f, x, mats["R"][lvl], mats["sqrtD"][lvl], geom)
-                field = (jax.vmap(ref)(field, xi[lvl + 1]) if sample_axis
-                         else ref(field, xi[lvl + 1]))
+                with jax.named_scope(f"icr.level{lvl + 1}"):
+                    field = (jax.vmap(ref)(field, xi[lvl + 1]) if sample_axis
+                             else ref(field, xi[lvl + 1]))
             return field
 
         backend = dispatch.select_backend()
@@ -266,13 +274,14 @@ class ICR:
             pmats = [self._level_axis_mats(mats, l) for l in range(start)]
             if pol is not None:
                 pmats = pol.cast_storage(pmats)
-            field = pyramid.refine_pyramid(
-                field, [xi[l + 1] for l in range(start)], pmats, geoms,
-                sample_axis=sample_axis, sample_block=s_b,
-                interpret=dispatch.pyramid_interpret(backend),
-                accum_dtype=(pol.accum_name if pol is not None
-                             else "float32"),
-            )
+            with jax.named_scope("icr.pyramid"):
+                field = pyramid.refine_pyramid(
+                    field, [xi[l + 1] for l in range(start)], pmats, geoms,
+                    sample_axis=sample_axis, sample_block=s_b,
+                    interpret=dispatch.pyramid_interpret(backend),
+                    accum_dtype=(pol.accum_name if pol is not None
+                                 else "float32"),
+                )
 
         for lvl in range(start, self.chart.n_levels):
             geom = LevelGeom.for_level(self.chart, lvl)
@@ -281,10 +290,11 @@ class ICR:
                 axis_mats = (mats["Rax"][lvl], mats["sqrtDax"][lvl])
             r = mats["R"][lvl] if "R" in mats else None
             d = mats["sqrtD"][lvl] if "sqrtD" in mats else None
-            field = dispatch.refine(
-                field, xi[lvl + 1], r, d, geom, axis_mats=axis_mats,
-                sample_axis=sample_axis, policy=pol, backend=backend,
-            )
+            with jax.named_scope(f"icr.level{lvl + 1}"):
+                field = dispatch.refine(
+                    field, xi[lvl + 1], r, d, geom, axis_mats=axis_mats,
+                    sample_axis=sample_axis, policy=pol, backend=backend,
+                )
             # keep XLA from fusing one level's phase-plane relayouts into
             # the next level's: at 10^8 points such fusions took minutes to
             # compile for a v5e (the dust slab: 224 s, 18 s with barriers)

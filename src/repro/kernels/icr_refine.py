@@ -274,7 +274,8 @@ def split_phases(x, s: int):
     """(..., n) -> (s, ..., n/s) with ``out[r, ..., m] = x[..., m·s + r]``:
     strided slices, so no array with a tiny minor dimension ever exists
     in HBM (XLA would pad it to 128 lanes)."""
-    return jnp.stack([x[..., r::s] for r in range(s)])
+    with jax.named_scope("icr.relayout"):
+        return jnp.stack([x[..., r::s] for r in range(s)])
 
 
 def interleave(planes, length: int):
@@ -284,21 +285,23 @@ def interleave(planes, length: int):
     interior-padded sum of billion-element planes does not finish
     compiling."""
     f = planes.shape[0]
-    out = jnp.zeros(planes.shape[1:-1] + (planes.shape[-1] * f,),
-                    planes.dtype)
-    for i in range(f):
-        out = out.at[..., i::f].set(planes[i])
-    return out[..., :length]
+    with jax.named_scope("icr.interleave"):
+        out = jnp.zeros(planes.shape[1:-1] + (planes.shape[-1] * f,),
+                        planes.dtype)
+        for i in range(f):
+            out = out.at[..., i::f].set(planes[i])
+        return out[..., :length]
 
 
 def stack_minor(planes):
     """(f, ..., n) -> (..., n, f) by per-slot updates (see
     ``interleave``)."""
     f = planes.shape[0]
-    out = jnp.zeros(planes.shape[1:] + (f,), planes.dtype)
-    for i in range(f):
-        out = out.at[..., i].set(planes[i])
-    return out
+    with jax.named_scope("icr.relayout"):
+        out = jnp.zeros(planes.shape[1:] + (f,), planes.dtype)
+        for i in range(f):
+            out = out.at[..., i].set(planes[i])
+        return out
 
 
 def _coarse_planes(coarse, plan: LaunchPlan):

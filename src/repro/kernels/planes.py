@@ -43,6 +43,7 @@ Two uses:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -241,49 +242,52 @@ def to_planes(x, axes_len, *, s: int, lead: int, layout):
     """Natural ``[*lead_dims, n_0, ..., n_{d-1}]`` (with ``lead`` leading
     batch dims merged into one) -> ``[s]*d + [batch] + [u in layout]``
     with ``u_a = axes_len[a]`` positions (zero-padded or cropped)."""
-    nd = len(axes_len)
-    batch = 1
-    for n in x.shape[:lead]:
-        batch *= n
-    x = x.reshape((batch,) + x.shape[lead:])
-    for a in range(nd):
-        want = axes_len[a] * s
-        have = x.shape[1 + a]
-        if have > want:
-            x = _slice(x, 0, want, 1 + a)
-        elif have < want:
-            pads = [(0, 0)] * x.ndim
-            pads[1 + a] = (0, want - have)
-            x = jnp.pad(x, pads)
-    shape = [batch]
-    for a in range(nd):
-        shape += [axes_len[a], s]
-    x = x.reshape(shape)
-    perm = [2 + 2 * a for a in range(nd)] + [0] \
-        + [1 + 2 * a for a in layout]
-    return x.transpose(perm)
+    with jax.named_scope("icr.relayout"):
+        nd = len(axes_len)
+        batch = 1
+        for n in x.shape[:lead]:
+            batch *= n
+        x = x.reshape((batch,) + x.shape[lead:])
+        for a in range(nd):
+            want = axes_len[a] * s
+            have = x.shape[1 + a]
+            if have > want:
+                x = _slice(x, 0, want, 1 + a)
+            elif have < want:
+                pads = [(0, 0)] * x.ndim
+                pads[1 + a] = (0, want - have)
+                x = jnp.pad(x, pads)
+        shape = [batch]
+        for a in range(nd):
+            shape += [axes_len[a], s]
+        x = x.reshape(shape)
+        perm = [2 + 2 * a for a in range(nd)] + [0] \
+            + [1 + 2 * a for a in layout]
+        return x.transpose(perm)
 
 
 def from_planes(y, *, layout):
     """Inverse relayout of a kernel output ``[P_a]*d + [batch] + [u in
     layout]`` -> natural ``[batch, u_0·P_0, ..., u_{d-1}·P_{d-1}]``."""
-    nd = len(layout)
-    # dims: phases 0..nd-1, batch nd, positions nd+1+i for axis layout[i]
-    perm = [nd]
-    for a in range(nd):
-        perm += [nd + 1 + list(layout).index(a), a]
-    z = y.transpose(perm)
-    shape = [z.shape[0]] + [z.shape[1 + 2 * a] * z.shape[2 + 2 * a]
-                            for a in range(nd)]
-    return z.reshape(shape)
+    with jax.named_scope("icr.relayout"):
+        nd = len(layout)
+        # dims: phases 0..nd-1, batch nd, positions nd+1+i for axis layout[i]
+        perm = [nd]
+        for a in range(nd):
+            perm += [nd + 1 + list(layout).index(a), a]
+        z = y.transpose(perm)
+        shape = [z.shape[0]] + [z.shape[1 + 2 * a] * z.shape[2 + 2 * a]
+                                for a in range(nd)]
+        return z.reshape(shape)
 
 
 def fine_to_planes(x, T, *, fsz: int, layout):
     """Natural fine-resolution ``[batch, T_0, f_0, ..., T_{d-1}, f_{d-1}]``
     (e.g. ξ, or a cotangent) -> the kernel output layout
     ``[fsz]*d + [batch] + [T in layout]``."""
-    nd = len(T)
-    x = x.reshape((x.shape[0],) + sum(((t, fsz) for t in T), ()))
-    perm = [2 + 2 * a for a in range(nd)] + [0] \
-        + [1 + 2 * a for a in layout]
-    return x.transpose(perm)
+    with jax.named_scope("icr.relayout"):
+        nd = len(T)
+        x = x.reshape((x.shape[0],) + sum(((t, fsz) for t in T), ()))
+        perm = [2 + 2 * a for a in range(nd)] + [0] \
+            + [1 + 2 * a for a in layout]
+        return x.transpose(perm)

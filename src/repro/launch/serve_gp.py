@@ -43,6 +43,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import hashlib
+import re
 import threading
 import time
 from typing import Callable, List, Optional
@@ -57,8 +58,16 @@ from repro.core.vi import Posterior
 from repro.distributed import elastic
 from repro.distributed.fault import DeviceLossError, ServingFaultSupervisor
 from repro.kernels import dispatch
+from repro.launch.spans import span
 
 _PAD_ROW = 2**30  # padding rows index past every request's eps stream
+# an HLO instruction line's name and the op_name of its metadata
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"',
+    re.M)
+# a named scope of ours in an op_name path, also inside a transformation's
+# wrapper (``vmap(serve.draw)``)
+_SCOPE = re.compile(r"(?<![\w.])((?:icr|serve)\.\w+)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,6 +127,13 @@ class GPRequest:
     mean: Optional[np.ndarray] = None
     std: Optional[np.ndarray] = None
     report: Optional[object] = None  # solvers.SolveReport (condition)
+    # stamped by the server: an id shared by the request's spans, and the
+    # perf_counter times it was admitted, had its first row packed and
+    # was done (admitted_s and started_s are their step's start)
+    rid: Optional[int] = None
+    admitted_s: Optional[float] = None
+    started_s: Optional[float] = None
+    finished_s: Optional[float] = None
     # internal: rows drawn so far (the per-request eps stream index),
     # the streaming Welford state (count, running mean, running M2),
     # and whether admission validation already ran
@@ -256,6 +272,10 @@ class GPFieldServer:
         self.dead_devices: set = set()
         self.degradations: list = []  # elastic.Degradation records
         self.last_recovery_s: Optional[float] = None  # fault -> first slab
+        self._phases: dict = {}  # span name -> (seconds, count): spans.span
+        self._rids = 0             # ids handed out at admission
+        self.requests_started = 0  # requests with a packed row
+        self.queue_wait_s = 0.0    # sum of started_s - admitted_s
         # data-conditioned solves (kind="condition", DESIGN.md §16)
         self.ckpt_root = ckpt_root
         self.solver_checkpoint_every = int(solver_checkpoint_every)
@@ -408,15 +428,17 @@ class GPFieldServer:
             """One row's excitation: (seed, row)-keyed noise around the
             posterior mean — or the request's own ξ when it supplied one
             (``xi_row`` is None when no row of the slab did)."""
-            k = jax.random.fold_in(jax.random.PRNGKey(seed), row)
-            ks = jax.random.split(k, len(shapes))
-            xs = [None] * len(shapes) if xi_row is None else xi_row
-            out = []
-            for kk, m, s, x in zip(ks, mean, std, xs):
-                base = m if x is None else jnp.where(use_xi,
-                                                     x.astype(m.dtype), m)
-                out.append(base + s * jax.random.normal(kk, m.shape, m.dtype))
-            return out
+            with jax.named_scope("serve.draw"):
+                k = jax.random.fold_in(jax.random.PRNGKey(seed), row)
+                ks = jax.random.split(k, len(shapes))
+                xs = [None] * len(shapes) if xi_row is None else xi_row
+                out = []
+                for kk, m, s, x in zip(ks, mean, std, xs):
+                    base = m if x is None else jnp.where(
+                        use_xi, x.astype(m.dtype), m)
+                    out.append(base
+                               + s * jax.random.normal(kk, m.shape, m.dtype))
+                return out
 
         if self.mesh is not None and self.shard == "chart":
             entry = self._build_chart_sharded(post, draw, shapes)
@@ -525,14 +547,20 @@ class GPFieldServer:
         return {"mats": mats, "fn": _slab_callable(make)}
 
     # -- admission -------------------------------------------------------------
+    @staticmethod
+    def _finish(req: GPRequest):
+        req.done = True
+        req.finished_s = time.perf_counter()
+
     def _reject(self, req: GPRequest, code: str, message: str):
         req.error = RequestError(code=code, message=message)
-        req.done = True
+        self._finish(req)
 
-    def _admit(self, queue: List[GPRequest]):
+    def _admit(self, queue: List[GPRequest], now: float):
         """Validate each request once, before any of its rows are packed:
         a rejected request never enters a slab, so it cannot poison the
-        streaming moments of the healthy requests packed beside it."""
+        streaming moments of the healthy requests packed beside it. Each
+        request admitted here gets its ``rid`` and ``admitted_s = now``."""
         shapes = self.posterior.icr.xi_shapes()
         served_theta = dict(self.posterior.icr.kernel.default_theta)
         served_theta.update(self.posterior.theta or {})
@@ -540,6 +568,8 @@ class GPFieldServer:
             if req.done or req.error or req._admitted:
                 continue
             req._admitted = True
+            self._rids += 1
+            req.rid, req.admitted_s = self._rids, now
             if req.kind not in ("sample", "moments", "condition") \
                     or not isinstance(req.n, (int, np.integer)) \
                     or req.n <= 0 or not 0 <= int(req.seed) < 2**31:
@@ -656,16 +686,22 @@ class GPFieldServer:
     def _execute_once(self, entry: dict, args: tuple) -> np.ndarray:
         """One slab attempt under the fault supervisor: transient errors
         retry with backoff, DeviceLossError propagates to the re-plan
-        path, wall time feeds the straggler monitor."""
+        path, wall time feeds the straggler monitor. An attempt is two
+        spans: ``serve.execute`` (dispatch and the device's work, as the
+        host waits for it) and ``serve.fetch`` (the copy to the host)."""
         warm = entry.pop("warm", None)
         if warm is not None:
             warm.join()
 
         def attempt():
             self.slabs_attempted += 1
+            slab = self.slabs_attempted
             if self.fault_injector is not None:
                 self.fault_injector(self)
-            return np.asarray(entry["fn"](*args), dtype=np.float32)
+            with span("serve.execute", self._phases, slab=slab):
+                out = jax.block_until_ready(entry["fn"](*args))
+            with span("serve.fetch", self._phases, slab=slab):
+                return np.asarray(out, dtype=np.float32)
 
         return self.supervisor.execute(attempt)
 
@@ -720,18 +756,22 @@ class GPFieldServer:
             reason="no feasible chart ring over the survivors"))
         return None
 
-    def _run_rows(self, rows: list) -> np.ndarray:
-        """Execute packed rows, chunked to the active entry's capacity.
-        A DeviceLossError mid-chunk re-plans onto the surviving mesh and
-        replays that chunk — the (seed, row) noise keys make the replay
-        reproduce the unfaulted results exactly."""
+    def _run_rows(self, rows: list, args: tuple) -> np.ndarray:
+        """Execute packed rows, chunked to the active entry's capacity;
+        ``args`` are the first chunk's, packed by the caller for the
+        active entry. A DeviceLossError mid-chunk re-plans onto the
+        surviving mesh and replays that chunk — the (seed, row) noise keys
+        make the replay reproduce the unfaulted results exactly."""
         outs = []
         i = 0
         recovery_t0 = None
         while i < len(rows):
             entry = self._entry
             chunk = rows[i:i + entry["capacity"]]
-            args = self._slab_args(entry, chunk)
+            if args is None:  # a later chunk, or a replay on a new entry
+                with span("serve.pack", self._phases,
+                          slab=self.slabs_attempted + 1):
+                    args = self._slab_args(entry, chunk)
             try:
                 out = self._execute_once(entry, args)
             except DeviceLossError as exc:
@@ -739,7 +779,9 @@ class GPFieldServer:
                     recovery_t0 = time.perf_counter()
                 self._on_device_loss(exc)
                 self.replayed_slabs += 1
+                args = None
                 continue  # replay the same chunk on the new entry
+            args = None
             if recovery_t0 is not None:
                 self.last_recovery_s = time.perf_counter() - recovery_t0
                 recovery_t0 = None
@@ -886,7 +928,7 @@ class GPFieldServer:
         del self.solve_reports[:-16]
         self.condition_rhs += k_real
         if report.status[0] not in ("converged", "dense"):
-            req.done = True
+            self._finish(req)
             req.error = RequestError(
                 "solve-failed",
                 f"posterior-mean solve ended '{report.status[0]}' "
@@ -906,34 +948,67 @@ class GPFieldServer:
         else:
             req.std = np.zeros(shape, np.float32)
         self.fields_delivered += 2
-        req.done = True
+        self._finish(req)
 
     # -- serving loop ----------------------------------------------------------
     def step(self, queue: List[GPRequest]) -> bool:
         """Pack one slab from the queue, execute it, scatter the results.
         Condition requests are served one per step (a whole batched solve
         is one unit of work); sample/moments rows pack into slabs.
-        Returns False when no request had demand (queue drained)."""
-        self._admit(queue)
-        for req in queue:
-            if not req.done and req.kind == "condition":
-                self._run_condition(req)
-                return True
+        Returns False when no request had demand (queue drained).
+
+        The call is the span ``serve.step``; a sample/moments slab runs
+        in its children ``serve.admit``, ``serve.pack`` (row assignment
+        and the slab's arguments; its ``rids`` are the packed requests'),
+        ``serve.execute``, ``serve.fetch`` and ``serve.scatter`` (row
+        copies and the Welford merge). Each carries ``slab``, the number
+        of the slab's first attempt (``slabs_attempted`` counts them)."""
+        now = time.perf_counter()
+        slab = self.slabs_attempted + 1
+        with span("serve.step", self._phases, slab=slab):
+            with span("serve.admit", self._phases, slab=slab):
+                self._admit(queue, now)
+            for req in queue:
+                if not req.done and req.kind == "condition":
+                    self._run_condition(req)
+                    return True
+            with span("serve.pack", self._phases, slab=slab) as ann:
+                rows = self._pack(queue, now)
+                if not rows:
+                    return False
+                ann.set_metadata(rids=" ".join(
+                    str(rid) for rid in sorted({r.rid for r, _ in rows})))
+                args = self._slab_args(self._entry, rows)
+            out = self._run_rows(rows, args)
+            self.rows_served += len(rows)
+            with span("serve.scatter", self._phases, slab=slab):
+                self._scatter(rows, out)
+        return True
+
+    def _pack(self, queue: List[GPRequest], now: float) -> list:
+        """Assign up to one slab of rows greedily in queue order: (request,
+        row index in its eps stream) pairs. A request's first packed row
+        stamps its ``started_s = now`` and its wait since admission."""
         cap = self._entry["capacity"]
-        rows = []  # (request, row index in its eps stream)
+        rows = []
         for req in queue:
             if req.done:
                 continue
             take = min(req.n - req._next_row, cap - len(rows))
+            if take > 0 and req.started_s is None:
+                req.started_s = now
+                self.requests_started += 1
+                self.queue_wait_s += now - req.admitted_s
             rows.extend((req, req._next_row + j) for j in range(take))
             req._next_row += take
             if len(rows) == cap:
                 break
-        if not rows:
-            return False
-        out = self._run_rows(rows)
-        self.rows_served += len(rows)
-        # scatter: contiguous runs per request (greedy packing keeps order)
+        return rows
+
+    def _scatter(self, rows: list, out: np.ndarray):
+        """Hand a slab's rows to their requests: contiguous runs per
+        request (greedy packing keeps order); a request whose last row
+        came back is done."""
         i = 0
         while i < len(rows):
             req = rows[i][0]
@@ -954,9 +1029,8 @@ class GPFieldServer:
                     self.fields_delivered += 2
                 else:
                     self.fields_delivered += len(req.fields)
-                req.done = True
+                self._finish(req)
             i = j
-        return True
 
     def run(self, requests: List[GPRequest], max_iters: int = 1_000_000):
         queue = list(requests)
@@ -970,11 +1044,9 @@ class GPFieldServer:
             it += 1
         for r in queue:
             if not r.done:  # max_iters exhausted: signal, never silently
-                r.error = RequestError(
-                    code="max-iters",
-                    message=f"server stopped after max_iters={max_iters} "
-                            f"slabs with {r.n - r._next_row} rows pending")
-                r.done = True
+                self._reject(r, "max-iters",
+                             f"server stopped after max_iters={max_iters} "
+                             f"slabs with {r.n - r._next_row} rows pending")
         return requests
 
     # -- introspection ---------------------------------------------------------
@@ -989,8 +1061,23 @@ class GPFieldServer:
         return self._entry["plan"][-1]["route"]
 
     def metrics(self) -> dict:
-        """Serving + fault counters for dashboards and the chaos suite."""
+        """Serving + fault counters for dashboards and the chaos suite.
+
+        Counters since construction; a reader takes differences over its
+        own window. ``slabs_run``/``rows_served``/``capacity`` give slab
+        fill. ``phase_s`` and ``phase_n`` map each span of ``step``
+        (``serve.step`` and its children ``serve.admit``, ``serve.pack``,
+        ``serve.execute``, ``serve.fetch``, ``serve.scatter``) to its
+        cumulative host seconds and count: where a slab's time goes,
+        without a profiler. ``queue_wait_s`` sums each request's wait from
+        admission to its first packed row (``started_s - admitted_s``)
+        over ``requests_started`` requests.
+        """
         return {
+            "phase_s": {k: v[0] for k, v in self._phases.items()},
+            "phase_n": {k: v[1] for k, v in self._phases.items()},
+            "queue_wait_s": self.queue_wait_s,
+            "requests_started": self.requests_started,
             "slabs_run": self.slabs_run,
             "slabs_attempted": self.slabs_attempted,
             "rows_served": self.rows_served,
@@ -1041,6 +1128,28 @@ class GPFieldServer:
         regression is caught by the golden diff, not by wall-time noise."""
         e = self._entry
         return e["fn"].variant(False).lower(*self._slab_args(e, []))
+
+    def op_scopes(self) -> dict:
+        """``{instruction name: scope path}`` of the compiled slab
+        executable: each instruction's ``op_name`` metadata cut to its
+        ``icr.*``/``serve.*`` named scopes (``"fusion.35":
+        "icr.level11/icr.interleave"``); instructions in no such scope are
+        left out. A profiler trace names device ops by instruction, so this
+        ties each op to its refinement level and phase. Compiling hits the
+        executable the slab ran, in memory or in the compile cache. The
+        persistent cache's key leaves metadata out, so an executable that
+        an older version of this code compiled into a shared cache keeps
+        that version's scopes (none, before they existed)."""
+        text = self.lowered_slab().compile().as_text()
+        out = {}
+        for name, op_name in _HLO_OP_NAME.findall(text):
+            found = _SCOPE.findall(op_name)
+            # a transformation can repeat the scope it wraps
+            path = [p for i, p in enumerate(found)
+                    if not i or p != found[i - 1]]
+            if path:
+                out[name] = "/".join(path)
+        return out
 
 
 # -- demo / smoke entry point ---------------------------------------------------
