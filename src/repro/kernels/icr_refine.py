@@ -18,7 +18,9 @@ plane ``f``), and the refinement matrices are coefficient planes
 multiply-accumulate of whole planes on the VPU: a refinement stencil is
 (4×5) at most, far below an MXU tile, and the VPU keeps full f32
 precision. Batch rows (samples, or the orthogonal lines of an N-D axis
-pass) ride the sublanes.
+pass) ride the sublanes. ``interleave`` puts the output planes back in
+natural order: one dense MXU relayout on long lines (the 1-D routes),
+strided updates on short ones (the N-D per-axis passes).
 
 Tiling: a level whose planes fit one block runs as a single block over
 the whole plane (any width). Otherwise blocks of ``b_f`` families (a
@@ -49,6 +51,7 @@ perturbed (``symbolic_zeros``).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -255,7 +258,8 @@ def refine_fwd_launch_plan(*, batch: int, t: int, coarse_len: int,
         params=dict(kind="fwd", charted=charted, noise=noise, t=t,
                     batch=batch, coarse_len=coarse_len, n_csz=n_csz,
                     n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk, nbb=nbb,
-                    halo=halo, lp=lp),
+                    halo=halo, lp=lp,
+                    interleave=interleave_form(n_fsz, tp)),
     )
 
 
@@ -278,18 +282,86 @@ def split_phases(x, s: int):
         return jnp.stack([x[..., r::s] for r in range(s)])
 
 
+# The shape rule of ``interleave`` (readings in its docstring)
+DENSE_MIN_LINE = 1024
+
+
+def interleave_form(f: int, n: int) -> str:
+    """The form ``interleave`` takes for ``f`` planes of lines of ``n``
+    positions: ``"dense"`` for long lines, ``"scatter"`` for short ones
+    and for ``f == 1`` (one whole update, a copy)."""
+    if f > 1 and n >= DENSE_MIN_LINE:
+        return "dense"
+    return "scatter"
+
+
+def _interleave_scatter(planes):
+    f = planes.shape[0]
+    out = jnp.zeros(planes.shape[1:-1] + (planes.shape[-1] * f,),
+                    planes.dtype)
+    for i in range(f):
+        out = out.at[..., i::f].set(planes[i])
+    return out
+
+
+def _interleave_dense(planes):
+    f, n = planes.shape[0], planes.shape[-1]
+    lead = planes.shape[1:-1]
+    n_pad = -(-n // 128) * 128
+    x = pad_to(planes.reshape(f, -1, n), (f, math.prod(lead), n_pad))
+    x = x.reshape(x.shape[:2] + (n_pad // 128, 128))
+    # e[i, j, m] = 1 where m = j·f + i: lane j of plane i lands on lane
+    # j·f + i of its row's 128·f-lane group
+    m = jnp.arange(128 * f)
+    e = m == (jnp.arange(128)[:, None] * f + jnp.arange(f)[:, None, None])
+    # bf16 times an exact 0/1 matrix is exact in one MXU pass; wider
+    # dtypes take HIGHEST, whose three bf16 pieces sum back exactly
+    prec = None if planes.dtype == jnp.bfloat16 else jax.lax.Precision.HIGHEST
+    y = jnp.einsum("frkj,fjm->rkm", x, e.astype(planes.dtype),
+                   precision=prec, preferred_element_type=F32)
+    return y.astype(planes.dtype).reshape(lead + (n_pad * f,))
+
+
 def interleave(planes, length: int):
     """Inverse of ``split_phases``: (f, ..., n) -> (..., n·f) cropped to
-    ``length``. Strided updates of one output buffer: the TPU compiler
-    handles them at any size, where a stack-and-reshape or an
-    interior-padded sum of billion-element planes does not finish
-    compiling."""
-    f = planes.shape[0]
+    ``length``, in the form ``interleave_form`` picks from the line
+    length ``n``.
+
+    * ``"scatter"``: strided updates ``out[..., i::f] = planes[i]``. XLA
+      lowers each to a scatter with one index per position, and a TPU
+      scatter costs per index: at the 1-D level-11 slab ``(4, 8,
+      524288)`` its 4 × 524,288 indices took 132.6 ms on a v5e, 63 ns an
+      index. Short lines give few indices: the N-D per-axis passes of
+      the dust slab (lines of 64-512 over 10^5-10^6 rows) took 31.8 ms
+      a slab.
+    * ``"dense"``: each row is cut into groups of 128 positions (the
+      line padded to a multiple of 128) and each group of the ``f``
+      planes is multiplied by a 0/1 matrix ``(f·128, 128·f)`` on the MXU,
+      fp32 at HIGHEST (three bf16 pieces that sum back exactly) and bf16
+      in one pass, both accumulated in f32. Rows stay a free dimension,
+      so no value reaches another row; a NaN or Inf spreads over its
+      own 128·f-element group. The scatter's result for normal values
+      (bit for bit on the CPU, equal over ±60 binades on a v5e); a -0.0
+      comes out +0.0 and a subnormal flushes to +0.0. On a v5e it
+      costs 26-41 ps an element, 0.43 ms at the level-11 slab, and it
+      never builds an array whose minor dimension is ``f`` (a
+      stack-and-reshape does, padded to 128 lanes: 3.3 ms and 1 GiB of
+      temporaries there).
+
+    The rule's threshold, from two v5e readings at 8 rows: at 1,024
+    positions both forms sit at the host's dispatch floor (0.178 and
+    0.179 ms a call), at 16,384 the scatter falls behind (0.338 against
+    0.188 ms). Shorter lines stay on the scatter, which pads nothing:
+    in the dust slab it ran at 34 ps an element, on a par with the dense
+    form, which would pad a line of 66 to 128. Rows do not enter: alone,
+    the dense form was the faster at every row count measured, e.g.
+    ``(4, 8192, 1024)`` 0.96 against 4.08 ms.
+    """
     with jax.named_scope("icr.interleave"):
-        out = jnp.zeros(planes.shape[1:-1] + (planes.shape[-1] * f,),
-                        planes.dtype)
-        for i in range(f):
-            out = out.at[..., i::f].set(planes[i])
+        if interleave_form(planes.shape[0], planes.shape[-1]) == "dense":
+            out = _interleave_dense(planes)
+        else:
+            out = _interleave_scatter(planes)
         return out[..., :length]
 
 
@@ -450,7 +522,8 @@ def refine_adjoint_launch_plan(*, batch: int, t: int, coarse_len: int,
         params=dict(kind="bwd", charted=charted, noise=noise, t=t,
                     batch=batch, coarse_len=coarse_len, n_csz=n_csz,
                     n_fsz=n_fsz, s=s, b_f=b_f, b_b=b_b, nblk=nblk_c,
-                    nbb=nbb, halo=halo),
+                    nbb=nbb, halo=halo,
+                    interleave=interleave_form(s, lc)),
     )
 
 

@@ -21,6 +21,8 @@ from repro.core.refine import (
 from repro.kernels import dispatch, nd
 from repro.kernels import ref as R
 from repro.kernels.icr_refine import (
+    interleave,
+    interleave_form,
     refine_charted_pallas,
     refine_stationary_pallas,
 )
@@ -589,3 +591,75 @@ class TestApplySqrtT:
             assert entry["vjp"]["route"] == dispatch.ROUTE_ND_FUSED + "-adjoint"
             assert entry["vjp"]["backend"] == entry["backend"]
             assert entry["vjp"]["block_families"] == entry["block_families"]
+
+
+# -- interleave: the dense and scatter forms and the shape rule ----------------
+def _interleave_ref(planes, length):
+    """Strided-slice assignment in numpy: the permutation with no scatter."""
+    p = np.asarray(planes)
+    f = p.shape[0]
+    out = np.empty(p.shape[1:-1] + (p.shape[-1] * f,), p.dtype)
+    for i in range(f):
+        out[..., i::f] = p[i]
+    return out[..., :length]
+
+
+# (f, rows, n, length, form the rule picks, poisoned row)
+INTERLEAVE_CASES = [
+    (4, 8, 4096, None, "dense", None),     # long lines: the 1-D routes
+    (2, 8, 4096, None, "dense", None),     # the adjoint's s planes
+    (4, 8, 1500, None, "dense", None),     # padded up to 12 lane groups
+    (2, 8, 1500, 2995, "dense", None),     # cropped to length
+    (4, 4096, 64, None, "scatter", None),  # many rows: N-D per-axis passes
+    (2, 4096, 66, 130, "scatter", None),
+    (2, 2048, 1024, None, "dense", None),  # many rows, long lines
+    (4, 8, 4096, None, "dense", 3),        # one row holds a NaN
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("f,rows,n,length,form,nan_row", INTERLEAVE_CASES)
+def test_interleave_matches_strided_reference(f, rows, n, length, form,
+                                              nan_row, dtype):
+    length = length or n * f
+    assert interleave_form(f, n) == form
+    rng = np.random.default_rng(f * rows + n)
+    # normal magnitudes over many binades: the dense form flushes
+    # subnormals and turns -0.0 into +0.0
+    x = rng.normal(size=(f, rows, n)) * np.exp(
+        rng.uniform(-20, 20, size=(f, rows, n)))
+    planes = jnp.asarray(x, jnp.float32).astype(dtype)
+    want = _interleave_ref(planes.astype(jnp.float32), length)
+    if nan_row is not None:
+        planes = planes.at[1, nan_row, n // 2].set(jnp.nan)
+    got = jax.jit(interleave, static_argnums=1)(planes, length)
+    assert got.dtype == dtype and got.shape == (rows, length)
+    got = np.asarray(got.astype(jnp.float32))
+    keep = [r for r in range(rows) if r != nan_row]
+    assert np.array_equal(got[keep], want[keep])
+    if nan_row is not None:
+        assert np.isnan(got[nan_row, (n // 2) * f + 1])
+
+
+def test_launch_plans_record_interleave_form():
+    """The 1-D served levels of the log chart go dense; the dust chart's
+    N-D per-axis level records what the rule chose for its planes."""
+    log = log_chart(1024, 11, n_csz=5, n_fsz=4, delta0=0.02,
+                    boundary="reflect")
+    groups = [g for g in dispatch.chart_launch_plans(log, samples=8)
+              if g["route"] == dispatch.ROUTE_CHARTED_1D]
+    assert [g["level"] for g in groups] == list(range(5, 11))
+    for g in groups:  # the pyramid covers levels 0-4
+        plans = dispatch.level_launch_plans(g["geom"], g["route"], samples=8)
+        assert [p.params["interleave"] for p in plans] == ["dense", "dense"]
+
+    dust = galactic_dust_chart((8, 64, 8), 5)
+    geom = LevelGeom.for_level(dust, dust.n_levels - 1)
+    plans = dispatch.level_launch_plans(geom, dispatch.ROUTE_AXES_ND,
+                                        samples=4)
+    for p in plans:
+        prm = p.params
+        f = prm["n_fsz"] if prm["kind"] == "fwd" else prm["s"]
+        n = p.outputs[0].array_shape[2]
+        assert prm["interleave"] == interleave_form(f, n)
+    assert [p.params["interleave"] for p in plans] == ["scatter"] * 6

@@ -96,3 +96,36 @@ def test_route_compiles_for_v5e(route, chart, dtype, one_chip):
         kinds.add(plan.params["kind"])
     # every route's backward launches adjoint kernels too
     assert kinds == {"fwd", "bwd"}, kinds
+
+
+def test_log_level11_interleave_is_dense_for_v5e(one_chip):
+    """The served log chart's last level (8 rows, 524,288 families):
+    the forward launch carries no scatter, and its interleave needs no
+    temporary beyond its output (a stack-and-reshape pads the 4-wide
+    minor dimension to 128 lanes: 1 GiB)."""
+    from repro.kernels.icr_refine import interleave, refine_charted_pallas
+
+    (grp,) = [g for g in dispatch.chart_launch_plans(
+        CHARTS["log"](), samples=8) if g["level"] == 10]
+    plan = grp["plans"][0]
+    p = plan.params
+    assert (p["kind"], p["batch"], p["t"], p["interleave"]) == (
+        "fwd", 8, 524288, "dense")
+    t, b, csz, fsz = p["t"], p["batch"], p["n_csz"], p["n_fsz"]
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    launch = jax.jit(lambda *a: refine_charted_pallas(
+        *a, n_csz=csz, n_fsz=fsz, block_families=p["b_f"],
+        batch_block=p["b_b"])).lower(
+        sds((b, p["coarse_len"])), sds((b, t, fsz)), sds((t, fsz, csz)),
+        sds((t, fsz, fsz))).compile()
+    text = launch.as_text()
+    assert "tpu_custom_call" in text
+    assert "scatter" not in text
+
+    planes = sds((fsz, b, plan.operand("fine").array_shape[2]))
+    mem = jax.jit(lambda x: interleave(x, t * fsz)).lower(
+        planes).compile().memory_analysis()
+    assert mem.temp_size_in_bytes <= mem.output_size_in_bytes
